@@ -10,6 +10,9 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from pathlib import Path
+
+from repro.launch.compile_cache import enable_compile_cache
 
 from . import (admission, beyond_bottleneck, beyond_budget, congestion,
                degraded, engine_throughput, fig6_strategies, fig7_online,
@@ -62,6 +65,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on benchmark name")
     args = ap.parse_args(argv)
+    enable_compile_cache(Path(__file__).resolve().parents[1])
 
     t_all = time.perf_counter()
     for name, fn, kw in BENCHES:

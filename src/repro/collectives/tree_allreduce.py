@@ -17,11 +17,6 @@ import numpy as np
 
 from .schedule import CompactOp, CompressOp, FoldOp, PermuteRound, ReduceProgram
 
-try:  # JAX >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _left_fold(buf, start, width, hi):
     """Strict sequential left fold of ``buf[start : start+width]``.
@@ -104,13 +99,15 @@ def tree_allreduce(x, prog: ReduceProgram, mesh, axis: str = "data"):
     x: (n_dev_along_axis, D) global view, or any array whose leading dim is
     sharded over `axis`.
     """
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(_apply_program, prog=prog, axis=axis),
         mesh=mesh,
         in_specs=jax.sharding.PartitionSpec(axis),
         out_specs=jax.sharding.PartitionSpec(),
     )
-    return fn(x)
+    # one compiled program per call: an eager shard_map dispatches every
+    # op of the program on its own
+    return jax.jit(fn)(x)
 
 
 def tree_allreduce_tree(grads, prog: ReduceProgram, mesh, axis: str = "data"):

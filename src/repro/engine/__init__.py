@@ -18,8 +18,8 @@ while-loop, and ``solve_congestion`` is its degenerate single-tree call.
 from .batched import (BatchResult, cache_stats, color_batch, gather_batch,
                       solve_batch, solve_forest)
 from .congestion import CongestionResult, solve_congestion, solve_fleet
-from .options import EngineOptions
+from .options import EngineOptions, pallas_fold
 
 __all__ = ["BatchResult", "CongestionResult", "EngineOptions", "cache_stats",
-           "color_batch", "gather_batch", "solve_batch", "solve_congestion",
-           "solve_fleet", "solve_forest"]
+           "color_batch", "gather_batch", "pallas_fold", "solve_batch",
+           "solve_congestion", "solve_fleet", "solve_forest"]

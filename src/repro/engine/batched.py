@@ -63,7 +63,7 @@ from ..core.tropical import BIG, minplus_batch
 from ..kernels.minplus.levelfold import (chain_fold, level_fold,
                                          minplus_fused, rho_up_from_edges,
                                          scaled_edges)
-from .options import EngineOptions, resolve_options
+from .options import EngineOptions, pallas_fold, resolve_options
 
 # back-compat alias: the engine's fused convolution now lives with the
 # level-fold kernel so both backends share one bit-exact implementation
@@ -670,9 +670,7 @@ def solve_forest(
     opts = resolve_options(options, engine_kw, "solve_forest")
     if k < 0:
         raise ValueError("budget k must be non-negative")
-    use_pallas = opts.use_pallas
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = pallas_fold(opts)
     inputs = _device_inputs(f, opts.dtype)
     if rho_root_add is not None and rho_scale is None:
         raise ValueError("rho_root_add extends a rho_scale re-solve; pass "

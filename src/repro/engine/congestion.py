@@ -106,7 +106,7 @@ from ..core.tree import Tree
 from ..kernels.minplus.levelfold import rho_up_from_edges, scaled_edges
 from .batched import (_color_body, _device_inputs, _gather_packed,
                       _override_inputs)
-from .options import EngineOptions, resolve_options
+from .options import EngineOptions, pallas_fold, resolve_options
 
 #: weights are rounded to this dyadic grid so effective rho stays exactly
 #: float32-representable on dyadic-rho trees (bit-identical engine/serial)
@@ -565,9 +565,7 @@ def solve_fleet(
         avails = [np.ones(trees[g].n, bool) if a is None
                   else np.array(a, dtype=bool, copy=True)
                   for a, g in zip(avails, tid_np)]
-    use_pallas = opts.use_pallas
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    use_pallas = pallas_fold(opts)
 
     # one Forest, one packing, one compiled executable for the whole loop
     f, lay = build_fleet_forest(trees, list(loads), tid_np, avails,

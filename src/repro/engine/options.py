@@ -24,6 +24,7 @@ import dataclasses
 import difflib
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 
 
@@ -35,7 +36,7 @@ class EngineOptions:
     dtype:        DP table dtype (float32 default; pass ``jnp.float64``
                   under ``jax_enable_x64`` for exactness on arbitrary rates)
     use_pallas:   None = auto (Pallas level-fold kernel on TPU, fused jnp
-                  elsewhere); True/False forces a backend
+                  elsewhere — :func:`pallas_fold`); True/False forces one
     interpret:    run the Pallas kernel body in Python (CPU validation)
     cap:          min(k, subtree) per-level budget-width truncation
     color:        False = costs-only mode (no traceback, no masks)
@@ -52,6 +53,14 @@ class EngineOptions:
     def replace(self, **changes) -> "EngineOptions":
         """A copy with ``changes`` applied (validated like the ctor)."""
         return dataclasses.replace(self, **changes)
+
+
+def pallas_fold(opts: EngineOptions) -> bool:
+    """Which level fold a solve runs: the Pallas kernel on TPU, the fused
+    jnp fold on every other backend, unless ``use_pallas`` forces one."""
+    if opts.use_pallas is None:
+        return jax.default_backend() == "tpu"
+    return bool(opts.use_pallas)
 
 
 _FIELDS = tuple(f.name for f in dataclasses.fields(EngineOptions))

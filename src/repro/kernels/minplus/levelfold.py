@@ -3,29 +3,23 @@
 The level-synchronous gather in ``repro.engine`` folds, for every internal
 node of a depth level, the min-plus convolutions of all its children's DP
 tables (the mCost chain of Algorithm 3), then applies the red/blue
-recurrence. PR 1 dispatched that as one ``pallas_call`` *per child index*
-(``O(max_children)`` launches per level) with the gathered child rows and
-every partial accumulator round-tripping through HBM. This module fuses
-the whole fold into a single kernel per level:
+recurrence. This module runs the whole fold of a level as one kernel:
 
-  * the kernel receives the *child level's* table block (children always
-    live exactly one level down; one batch element per grid step), gathers
-    each child's rows out of it in-kernel, and chains the min-plus
-    convolutions **in-register** — the ``(rows, K)`` partial accumulators
-    never leave VMEM;
+  * the children's rows are gathered in XLA (children always live exactly
+    one level down) and laid out budget-major, the parent columns filling
+    the ``(8, 128)`` vreg tile; the kernel chains the min-plus
+    convolutions with the ``(rows, K)`` partial accumulators in VMEM
+    scratch, one child per step of a reduction grid axis;
   * the red chain (child rows ``1..nl``), the blue chain (child row 1),
     the availability mask, the blue budget shift and the at-most-k
     ``cummin`` all happen in the same kernel body, so a level costs one
     launch and one HBM write (the level's output block).
 
 ``level_fold`` is the dispatcher: ``use_pallas=True`` runs the Pallas
-kernel (``interpret=True`` executes its body in Python — the CPU-container
-validation mode; budget widths are lane-padded to 128 inside
-``level_fold_pallas``; TPU tiling note: the in-kernel child gathers land
-on the sublane axis, which is the part to revisit if a real-TPU lowering
-rejects the kernel), ``use_pallas=False`` runs ``level_fold_jnp``, a fused
-jnp formulation of the identical math that XLA fuses into one loop nest on
-CPU/GPU.
+kernel (compiled by Mosaic on TPU; ``interpret=True`` executes its body in
+Python, the CPU validation mode), ``use_pallas=False`` runs
+``level_fold_jnp``, a fused jnp formulation of the identical math that XLA
+fuses into one loop nest.
 
 All arithmetic runs on the finite ``BIG`` sentinel from
 ``repro.core.tropical`` (never ``inf``: padded slots multiply by zero
@@ -39,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ...core.tropical import BIG
 
@@ -143,63 +138,24 @@ def scaled_edges(rho_edge: jax.Array, scale: jax.Array,
     return edges.at[jnp.arange(B), root_idx].add(extra)
 
 
-def _minplus_loop(a: jax.Array, b: jax.Array) -> jax.Array:
-    """minplus_fused spelled as a fori_loop (for kernel bodies).
-
-    Identical candidate order and BIG shift padding — bit-identical
-    results — but O(1) HLO in the budget width, so lane-padded kernels
-    don't pay a 128-step unroll at trace time.
-    """
-    rows, kk = a.shape
-    a_pad = jnp.concatenate([jnp.full((rows, kk), BIG, a.dtype), a], axis=1)
-
-    def body(j, acc):
-        seg = jax.lax.dynamic_slice(a_pad, (0, kk - j), (rows, kk))
-        bj = jax.lax.dynamic_slice(b, (0, j), (rows, 1))
-        return jnp.minimum(acc, seg + bj)
-
-    return jax.lax.fori_loop(1, kk, body, a + b[:, :1])
-
-
-def _fold_math(xs, xb, kid, load, send, avail, rho, nl, kcap):
-    """Shared recurrence body: chain children, apply red/blue, cummin.
-
-    xs:   (C, nl, kcap) child-level tables at rows 1..nl, all-zeros
-          identity appended at index C-1
-    xb:   (C, kcap)     the same at row 1 (the blue chain operand)
-    kid:  (W, max_c) int32 child-level-local indices (sentinel = C-1)
-    load, send: (W,) float; avail: (W,) bool; rho: (W, nl) float
-    returns (W, nl, kcap)
-    """
-    w, max_c = kid.shape
-    dt = xs.dtype
-    acc_r = jnp.take(xs, kid[:, 0], axis=0)            # (W, nl, kcap)
-    acc_b = jnp.take(xb, kid[:, 0], axis=0)            # (W, kcap)
-    for m in range(1, max_c):
-        ch_r = jnp.take(xs, kid[:, m], axis=0)
-        ch_b = jnp.take(xb, kid[:, m], axis=0)
-        # one fused convolution over all (v, ell) rows + the blue rows
-        a = jnp.concatenate([acc_r.reshape(-1, kcap), acc_b])
-        b = jnp.concatenate([ch_r.reshape(-1, kcap), ch_b])
-        y = _minplus_loop(a, b)
-        acc_r = y[: w * nl].reshape(w, nl, kcap)
-        acc_b = y[w * nl :]
-    rl = rho[:, :, None]                               # (W, nl, 1)
-    red = acc_r + load[:, None, None] * rl
-    # blue: budget shifts by one (v spends a slot on itself)
-    blue = jnp.concatenate(
-        [jnp.full((w, nl, 1), BIG, dt),
-         acc_b[:, None, :-1] + send[:, None, None] * rl], axis=-1)
-    blue = jnp.where(avail[:, None, None], blue, BIG)
-    out = jnp.minimum(red, blue)
-    return jax.lax.cummin(out, axis=2)                 # at-most-k monotone
+def _gather_children(xs, xb, kid):
+    """Every parent's child rows, ``(B, W, max_c, nl, kcap)`` red and
+    ``(B, W, max_c, kcap)`` blue, gathered over the leading batch axis
+    (sentinel children read the appended identity at index C-1)."""
+    B, W, max_c = kid.shape
+    _, _, nl, kcap = xs.shape
+    flat = kid.reshape(B, W * max_c)
+    g_r = jnp.take_along_axis(xs, flat[:, :, None, None], axis=1)
+    g_b = jnp.take_along_axis(xb, flat[:, :, None], axis=1)
+    return (g_r.reshape(B, W, max_c, nl, kcap),
+            g_b.reshape(B, W, max_c, kcap))
 
 
 def level_fold_jnp(xs, xb, kid, load, send, avail, rho, *, nl: int,
                   kcap: int):
-    """Fused-jnp level fold — batched :func:`_fold_math` math, spelled with
-    ``take_along_axis`` over the leading batch axis (cheaper for XLA:CPU to
-    compile than a vmapped per-instance body).
+    """Fused-jnp level fold, spelled with ``take_along_axis`` over the
+    leading batch axis (cheaper for XLA:CPU to compile than a vmapped
+    per-instance body).
 
     xs: (B, C, nl, kcap) the child level's tables at rows 1..nl, identity
     (all-zeros) appended at index C-1; xb: (B, C, kcap) the same at row 1
@@ -210,11 +166,7 @@ def level_fold_jnp(xs, xb, kid, load, send, avail, rho, *, nl: int,
     """
     B, W, max_c = kid.shape
     dt = xs.dtype
-    # gather every child's red rows + blue row in one go: (B, W, max_c, ...)
-    g_r = jnp.take_along_axis(xs, kid.reshape(B, -1)[:, :, None, None],
-                              axis=1).reshape(B, W, max_c, nl, kcap)
-    g_b = jnp.take_along_axis(xb, kid.reshape(B, -1)[:, :, None],
-                              axis=1).reshape(B, W, max_c, kcap)
+    g_r, g_b = _gather_children(xs, xb, kid)
     rows_r = jnp.moveaxis(g_r, 2, 0).reshape(max_c, B * W * nl, kcap)
     rows_b = jnp.moveaxis(g_b, 2, 0).reshape(max_c, B * W, kcap)
     chs = jnp.concatenate([rows_r, rows_b], axis=1)    # (max_c, R, kcap)
@@ -231,49 +183,165 @@ def level_fold_jnp(xs, xb, kid, load, send, avail, rho, *, nl: int,
     return jax.lax.cummin(out, axis=3)                 # at-most-k monotone
 
 
-def _levelfold_kernel(xs_ref, xb_ref, kid_ref, load_ref, send_ref,
-                      avail_ref, rho_ref, o_ref, *, nl: int, kcap: int):
-    out = _fold_math(
-        xs_ref[0], xb_ref[0], kid_ref[0], load_ref[0],
-        send_ref[0], avail_ref[0] > 0, rho_ref[0], nl, kcap)
-    o_ref[0] = out
+LANE, SUBLANE = 128, 8
+VMEM_BUDGET = 12 * 2**20    # under the 16 MiB scoped-VMEM default of v5e
 
 
-LANE = 128
+def _minplus_into(acc_ref, acc_at, x_ref, x_at, pad_ref, kcap: int):
+    """``acc <- minplus_fused(acc, x)`` on one budget-major slab.
+
+    ``acc_ref[acc_at]`` / ``x_ref[x_at]`` are ``(kcap, TS, 128)``: budget
+    on the leading axis, parent columns on the (sublane, lane) tile. The
+    j-shift of :func:`minplus_fused` is a dynamic leading-axis window of
+    ``pad_ref`` (``kcap - 1`` BIG rows, then the accumulator), so the
+    candidate set is exactly the jnp one — shifted accumulator plus the
+    child's entry j, BIG where the shift runs off the front.
+    """
+    a = acc_ref[acc_at]
+    pad_ref[kcap - 1 :] = a
+
+    def body(j, run):
+        return jnp.minimum(run, pad_ref[pl.ds(kcap - 1 - j, kcap)]
+                           + x_ref[(*x_at, j)])
+
+    acc_ref[acc_at] = jax.lax.fori_loop(1, kcap, body, a + x_ref[(*x_at, 0)])
+
+
+def _levelfold_kernel(xr_ref, xb_ref, load_ref, send_ref, avail_ref, rho_ref,
+                      o_ref, accr_ref, accb_ref, pad_ref, *, lt: int,
+                      kcap: int):
+    """Grid (parent tiles, row tiles, child index m); m is the reduction
+    axis: child 0 seeds the accumulators, children 1.. fold into them,
+    and the last child's step applies red/blue, the availability mask and
+    the at-most-k cummin, writing the output block once."""
+    m = pl.program_id(2)
+
+    @pl.when(m == 0)
+    def _():
+        accr_ref[...] = xr_ref[0]
+        accb_ref[...] = xb_ref[0]
+        if kcap > 1:
+            pad_ref[: kcap - 1] = jnp.full(
+                (kcap - 1, *pad_ref.shape[1:]), BIG, pad_ref.dtype)
+
+    @pl.when(m > 0)
+    def _():
+        def fold_row(r, c):
+            _minplus_into(accr_ref, (r,), xr_ref, (0, r), pad_ref, kcap)
+            return c
+
+        jax.lax.fori_loop(0, lt, fold_row, 0)
+        _minplus_into(accb_ref, (), xb_ref, (0,), pad_ref, kcap)
+
+    @pl.when(m == pl.num_programs(2) - 1)
+    def _():
+        avail = avail_ref[...] != 0
+        load, send = load_ref[...], send_ref[...]
+        acc_b = accb_ref[...]
+
+        def emit(r, c):
+            rl = rho_ref[r]                            # (TS, 128)
+            red = accr_ref[r] + load * rl              # (kcap, TS, 128)
+            o_ref[r, 0] = jnp.minimum(red[0], BIG)     # blue needs budget
+            if kcap > 1:
+                blue = jnp.where(avail, acc_b[:-1] + send * rl, BIG)
+                o_ref[r, 1:] = jnp.minimum(red[1:], blue)
+            s = 1
+            while s < kcap:                            # prefix-min doubling
+                o_ref[r, s:] = jnp.minimum(o_ref[r, s:], o_ref[r, : kcap - s])
+                s *= 2
+            return c
+
+        jax.lax.fori_loop(0, lt, emit, 0)
+
+
+def _tiles(P: int, nl: int, kcap: int) -> tuple[int, int, int, int]:
+    """Tile sizes for ``P`` parent columns: ``(ts, lt, pr, vmem)``.
+
+    ``ts`` sublane rows of 128 parent columns per block (a power of two in
+    8..64, about 1024 budget x column rows per slab, or the whole padded
+    column count when it is smaller than one tile); ``lt`` the largest
+    divisor of ``nl`` whose blocks fit :data:`VMEM_BUDGET`; ``pr`` the
+    padded column-row count; ``vmem`` the bytes one grid step holds.
+    """
+    pr = -(-P // LANE)
+    ts = SUBLANE
+    while ts < 64 and 2 * ts * kcap <= 1024:
+        ts *= 2
+    if pr <= ts:
+        ts = pr
+    else:
+        pr = -(-pr // ts) * ts
+    tile = 4 * max(ts, SUBLANE) * LANE
+
+    def vmem(lt):
+        # double-buffered inputs and output, accumulators, shift window
+        return tile * (2 * (lt * kcap + kcap + 3 + lt) + 2 * lt * kcap
+                       + lt * kcap + kcap + 2 * kcap - 1)
+
+    lt = max(d for d in range(1, nl + 1)
+             if nl % d == 0 and (d == 1 or vmem(d) <= VMEM_BUDGET))
+    return ts, lt, pr, vmem(lt)
 
 
 def level_fold_pallas(xs, xb, kid, load, send, avail, rho, *, nl: int,
                       kcap: int, interpret: bool = False):
     """One-launch-per-level Pallas fold; same contract as level_fold_jnp.
 
-    Grid is the batch: each step holds one instance's child-level table
-    block in VMEM, gathers child rows from it and chains the convolutions
-    without writing partials back to HBM. The budget axis is padded to
-    the 128-lane boundary with BIG (same discipline as ops.minplus —
-    min-plus output column i only reads operand columns <= i, so BIG
-    lanes never leak into the real prefix) and sliced back after.
+    The child rows are gathered in XLA (:func:`_gather_children`) and laid
+    out *budget-major*: the budget axis is a leading block axis and the
+    ``B * W`` parent columns fill the ``(8, 128)`` vreg tile. The
+    min-plus shift is then a leading-axis window and the blue budget
+    shift and the cummin are leading-axis slices — no lane shuffles and
+    no lane padding. The grid tiles parent columns and barrier rows and
+    runs the child chain as its innermost reduction axis, so one step
+    holds one child's block (see :func:`_tiles`) whatever the level's
+    width; the ``(rows, kcap)`` partial accumulators stay in VMEM scratch.
+    The candidate set of every min is the jnp one, so the result is
+    bit-identical to :func:`level_fold_jnp`.
     """
-    B, C, _, _ = xs.shape
-    _, W, max_c = kid.shape
+    B, W, max_c = kid.shape
     dt = xs.dtype
-    kp = ((kcap + LANE - 1) // LANE) * LANE
-    xs = jnp.pad(xs, ((0, 0), (0, 0), (0, 0), (0, kp - kcap)),
-                 constant_values=BIG)
-    xb = jnp.pad(xb, ((0, 0), (0, 0), (0, kp - kcap)), constant_values=BIG)
+    P = B * W
+    ts, lt, pr, vmem = _tiles(P, nl, kcap)
+    g_r, g_b = _gather_children(xs, xb, kid)
 
-    def bspec(shape):
-        return pl.BlockSpec((1, *shape), lambda b: (b,) + (0,) * len(shape))
+    def cols(x, lead):
+        """(B, W, *lead) -> (*lead, pr, 128), parent columns zero-padded."""
+        nd = len(lead)
+        x = jnp.transpose(x, (*range(2, 2 + nd), 0, 1)).reshape(*lead, P)
+        x = jnp.pad(x, [(0, 0)] * nd + [(0, pr * LANE - P)])
+        return x.reshape(*lead, pr, LANE)
 
+    xr = cols(g_r, (max_c, nl, kcap))
+    xbb = cols(g_b, (max_c, kcap))
+    vec = [cols(v.astype(t)[..., None], (1,))[0]
+           for v, t in ((load, dt), (send, dt), (avail, jnp.int32))]
+    rl = cols(rho, (nl,))
+    tl = (ts, LANE)
+    params = dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
+    if vmem > VMEM_BUDGET:
+        params["vmem_limit_bytes"] = vmem + 4 * 2**20
     out = pl.pallas_call(
-        functools.partial(_levelfold_kernel, nl=nl, kcap=kp),
-        grid=(B,),
-        in_specs=[bspec((C, nl, kp)), bspec((C, kp)), bspec((W, max_c)),
-                  bspec((W,)), bspec((W,)), bspec((W,)), bspec((W, nl))],
-        out_specs=bspec((W, nl, kp)),
-        out_shape=jax.ShapeDtypeStruct((B, W, nl, kp), dt),
+        functools.partial(_levelfold_kernel, lt=lt, kcap=kcap),
+        grid=(pr // ts, nl // lt, max_c),
+        in_specs=[
+            pl.BlockSpec((1, lt, kcap, *tl), lambda p, l, m: (m, l, 0, p, 0)),
+            pl.BlockSpec((1, kcap, *tl), lambda p, l, m: (m, 0, p, 0)),
+            *[pl.BlockSpec(tl, lambda p, l, m: (p, 0))] * 3,
+            pl.BlockSpec((lt, *tl), lambda p, l, m: (l, p, 0)),
+        ],
+        out_specs=pl.BlockSpec((lt, kcap, *tl),
+                               lambda p, l, m: (l, 0, p, 0)),
+        out_shape=jax.ShapeDtypeStruct((nl, kcap, pr, LANE), dt),
+        scratch_shapes=[pltpu.VMEM((lt, kcap, *tl), dt),
+                        pltpu.VMEM((kcap, *tl), dt),
+                        pltpu.VMEM((2 * kcap - 1, *tl), dt)],
+        compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
-    )(xs, xb, kid, load, send, avail.astype(jnp.int32), rho)
-    return out[..., :kcap]
+    )(xr, xbb, *vec, rl)
+    out = out.reshape(nl, kcap, pr * LANE)[:, :, :P]
+    return jnp.transpose(out.reshape(nl, kcap, B, W), (2, 3, 0, 1))
 
 
 def level_fold(xs, xb, kid, load, send, avail, rho, *, nl: int, kcap: int,

@@ -17,15 +17,29 @@ interpret-mode kernel matches the fused path bit-for-bit.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .levelfold import _minplus_loop
+from ...core.tropical import BIG
+
+
+def _minplus_loop(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``minplus_fused`` spelled as a fori_loop: identical candidates and
+    BIG shift padding (bit-identical results), but O(1) HLO in the
+    budget width, so the lane-padded kernel doesn't pay a 128-step unroll
+    at trace time."""
+    rows, kk = a.shape
+    a_pad = jnp.concatenate([jnp.full((rows, kk), BIG, a.dtype), a], axis=1)
+
+    def body(j, acc):
+        seg = jax.lax.dynamic_slice(a_pad, (0, kk - j), (rows, kk))
+        bj = jax.lax.dynamic_slice(b, (0, j), (rows, 1))
+        return jnp.minimum(acc, seg + bj)
+
+    return jax.lax.fori_loop(1, kk, body, a + b[:, :1])
 
 
 def _minplus_kernel(a_ref, b_ref, o_ref):
-    # one shared definition of the BIG-padded j-shift reduction (also the
-    # level-fold kernel's inner loop) — candidate order is what keeps the
-    # kernels bit-identical to the fused jnp path
     o_ref[...] = _minplus_loop(a_ref[...], b_ref[...])
 
 

@@ -24,8 +24,8 @@ def _topk_kernel(x_ref, v_ref, i_ref, *, k: int):
         mag = jnp.abs(cur)
         idx = jnp.argmax(mag, axis=1)                       # (TB,)
         val = jnp.take_along_axis(cur, idx[:, None], axis=1)  # (TB, 1)
-        pl.store(v_ref, (slice(None), pl.dslice(j, 1)), val.astype(v_ref.dtype))
-        pl.store(i_ref, (slice(None), pl.dslice(j, 1)), idx[:, None].astype(jnp.int32))
+        v_ref[:, pl.ds(j, 1)] = val.astype(v_ref.dtype)
+        i_ref[:, pl.ds(j, 1)] = idx[:, None].astype(jnp.int32)
         cur = jnp.where(
             jax.lax.broadcasted_iota(jnp.int32, (tb, d), 1) == idx[:, None],
             0.0, cur)

@@ -33,7 +33,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..checkpoint import ckpt
 from ..collectives import chip_level_tree
-from ..collectives.tree_allreduce import reduce_local, _shard_map
+from ..collectives.tree_allreduce import reduce_local
 from ..configs import ARCHS
 from ..data.pipeline import DataConfig, SyntheticLM
 from ..models import api
@@ -42,6 +42,12 @@ from ..optim import adamw
 from ..optim.compression import (CompressionConfig, compress_tree,
                                  init_error_feedback, payload_bytes)
 from ..runtime import Orchestrator, OrchestratorConfig
+from .mesh import auto_mesh
+
+
+# --preset-100m: the ~100M-parameter shape of a family (at qwen3-32b, 67M)
+PRESET_100M = dict(n_layers=8, d_model=512, n_heads=8, n_kv_heads=8,
+                   d_ff=2048, vocab=32_768, head_dim=0)
 
 
 def dp_fleet(n_devices: int):
@@ -58,12 +64,15 @@ def dp_fleet(n_devices: int):
 
 def make_step(cfg: ModelConfig, ocfg: adamw.AdamWConfig, mesh, prog,
               grad_scale: float,
-              ccfg: CompressionConfig = CompressionConfig()):
+              ccfg: CompressionConfig = CompressionConfig(),
+              psum: bool = False):
     """jit(shard_map(local grad [+ compress] + SOAR reduce) -> adamw).
 
     Compression (top-k/int8 with error feedback) happens on each worker's
     LOCAL gradient before the reduction — the paper's PS use case: sparse
-    worker messages, in-network union-sum aggregation.
+    worker messages, in-network union-sum aggregation. ``psum=True``
+    reduces the gradients with ``jax.lax.psum`` instead of the SOAR
+    program: the reference step the SOAR reduce is checked against.
     """
     lfn = api.loss_fn(cfg)
     n_dev = prog.n_dev
@@ -76,15 +85,16 @@ def make_step(cfg: ModelConfig, ocfg: adamw.AdamWConfig, mesh, prog,
         grads, ef = compress_tree(grads, ef, ccfg)
         if n_dev > 1:
             ef = jax.tree.map(lambda e: e[None], ef)
+            reduce = ((lambda g: jax.lax.psum(g, "data")) if psum else
+                      (lambda g: reduce_local(g, prog, "data")))
             grads = jax.tree.map(
-                lambda g: reduce_local(g, prog, "data") * (grad_scale / n_dev),
-                grads)
+                lambda g: reduce(g) * (grad_scale / n_dev), grads)
             loss = jax.lax.pmean(loss, "data")
             metrics = jax.tree.map(lambda m: jax.lax.pmean(m, "data"), metrics)
         return loss, metrics, grads, ef
 
     if n_dev > 1:
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local_grads, mesh=mesh,
             in_specs=(P(), P("data"), P("data")),
             out_specs=(P(), P(), P(), P("data")),
@@ -157,8 +167,7 @@ def main(argv=None):
 
     cfg = ARCHS[args.arch]
     if args.preset_100m:
-        cfg = cfg.reduced(n_layers=8, d_model=512, n_heads=8, n_kv_heads=8,
-                          d_ff=2048, vocab=32_768, head_dim=0)
+        cfg = cfg.reduced(**PRESET_100M)
     elif args.reduced:
         cfg = cfg.reduced()
     if cfg.param_count() > 1e9:
@@ -166,7 +175,7 @@ def main(argv=None):
     print(f"arch={cfg.name} params={cfg.param_count():,}")
 
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = auto_mesh((n_dev,), ("data",))
     topo = dp_fleet(n_dev)
     orch = Orchestrator(topo, OrchestratorConfig(k=args.k,
                                                  strategy=args.strategy))

@@ -37,11 +37,6 @@ from ..parallel.sharding import cs, current_mesh, current_rules
 from .config import ModelConfig
 from .layers import dense_init, dtype_of, init_mlp, mlp_einsum, apply_mlp
 
-try:  # JAX >= 0.6
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def init_moe(key, cfg: ModelConfig):
     E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff_expert
@@ -246,7 +241,7 @@ def _moe_forward_ep(p, x, cfg: ModelConfig, mesh, rules):
         return jax.lax.psum(y, "model"), aux
 
     xt = cs(x.reshape(N, d), "batch", None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, rw_spec, w_specs),
         out_specs=(tok_spec, P()),
@@ -339,7 +334,7 @@ def _moe_forward_ep_a2a(p, x, cfg: ModelConfig, mesh, rules):
         return y, aux
 
     xt = cs(x.reshape(N, d), "tokens_flat", None)
-    y, aux = _shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, rw_spec, w_specs),
         out_specs=(tok_spec, P()),

@@ -539,6 +539,7 @@ class ChaosTrainer:
         from ..checkpoint import ckpt as _ckpt
         from ..configs import ARCHS
         from ..data.pipeline import DataConfig, SyntheticLM
+        from ..launch.mesh import auto_mesh
         from ..models import api
         from ..optim import adamw
         from ..optim.compression import (CompressionConfig,
@@ -555,7 +556,7 @@ class ChaosTrainer:
         self.cfg = ARCHS[arch].reduced()
         self.ocfg = adamw.AdamWConfig()
         self.ccfg = CompressionConfig()
-        self.mesh = jax.make_mesh((n_dev,), ("data",))
+        self.mesh = auto_mesh((n_dev,), ("data",))
         self.global_batch = global_batch or max(4, n_dev)
         if self.global_batch % n_dev:
             raise ValueError(f"global_batch {self.global_batch} not "

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import auto_mesh
 from repro.collectives import (
     build_program, chip_level_tree, fail_devices, plan, tree_allreduce,
 )
@@ -19,28 +20,29 @@ from repro.core.reduce import all_blue, all_red
 
 def main():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     topo = chip_level_tree(n_pods=2, racks_per_pod=2, chips_per_rack=2)
     assert topo.n_devices == 8
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
     want = np.asarray(x).sum(0)
 
-    checked = 0
+    # every distinct placement of the sweep (k = 0 is all-red and k = n
+    # all-blue for every strategy, so each placement runs once)
+    progs = {}
     for k in (0, 1, 2, 4, topo.tree.n):
         for strategy in ("soar", "top", "max", "random"):
             blue, prog = plan(topo, k, strategy=strategy)
-            with mesh:
-                got = tree_allreduce(x, prog, mesh, "data")
-            np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
-                                       atol=1e-5)
-            checked += 1
-    # extremes
+            progs.setdefault(np.asarray(blue, bool).tobytes(), prog)
     for blue in (all_red(topo.tree), all_blue(topo.tree)):
-        prog = build_program(topo, blue)
+        progs.setdefault(np.asarray(blue, bool).tobytes(),
+                         build_program(topo, blue))
+    checked = 0
+    for prog in progs.values():
         with mesh:
             got = tree_allreduce(x, prog, mesh, "data")
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
+                                   atol=1e-5)
         checked += 1
 
     # SOAR cost dominance across programs at equal budget

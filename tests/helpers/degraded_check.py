@@ -23,13 +23,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import auto_mesh
 from repro.collectives import (build_program, chip_level_tree,
                                degrade_switches, tree_allreduce)
 
 
 def check_executor_bitwise():
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = auto_mesh((8,), ("data",))
     topo = chip_level_tree(n_pods=2, racks_per_pod=2, chips_per_rack=2)
     t = topo.tree
     rng = np.random.default_rng(0)

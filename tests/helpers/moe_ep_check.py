@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import auto_mesh
 from repro.configs import ARCHS
 from repro.models import moe
 from repro.parallel.sharding import axis_rules, make_rules
@@ -17,7 +18,7 @@ from repro.parallel.sharding import axis_rules, make_rules
 
 def main():
     assert jax.device_count() == 8
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     # high capacity factor -> no drops -> EP must match dense exactly
     cfg = ARCHS["deepseek-v2-236b"].reduced(
         n_experts=8, top_k=2, d_ff_expert=32, capacity_factor=8.0,

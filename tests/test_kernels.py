@@ -62,6 +62,44 @@ def test_minplus_engine_fused_path_matches_ref(rows, k):
 
 
 # ---------------------------------------------------------------------------
+# level fold: budget-major Pallas kernel == fused jnp fold, bit for bit
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,C,W,max_c,nl,kcap,budget", [
+    (2, 7, 3, 1, 2, 3, None),            # one child: seed + epilogue only
+    (3, 9, 4, 3, 4, 1, None),            # budget width 1: no shift window
+    (4, 40, 20, 2, 5, 17, None),         # one tile, whole level
+    (3, 700, 1000, 2, 3, 40, None),      # several parent-column tiles
+    (2, 30, 12, 3, 6, 9, 1),             # one barrier row per tile
+])
+def test_level_fold_pallas_matches_jnp(monkeypatch, B, C, W, max_c, nl,
+                                       kcap, budget):
+    from repro.core.tropical import BIG
+    from repro.kernels.minplus import levelfold as lf
+    if budget is not None:
+        monkeypatch.setattr(lf, "VMEM_BUDGET", budget)
+    ts, lt, pr, _ = lf._tiles(B * W, nl, kcap)
+    assert (budget is None) == (lt == nl)
+    rng = np.random.default_rng(B * 1000 + W)
+    xs = np.sort(rng.integers(0, 60, (B, C, nl, kcap)), axis=-1)[..., ::-1]
+    xs = xs.astype(np.float32)
+    xs[rng.random(xs.shape) < 0.1] = 3 * BIG     # entries past BIG exist
+    xs[:, -1] = 0.0                              # min-plus identity slot
+    xb = np.ascontiguousarray(xs[:, :, 0])
+    kid = rng.integers(0, C, (B, W, max_c)).astype(np.int32)
+    load = rng.integers(0, 5, (B, W)).astype(np.float32)
+    send = rng.integers(0, 3, (B, W)).astype(np.float32)
+    avail = rng.random((B, W)) < 0.7
+    rho = rng.integers(1, 4, (B, W, nl)).astype(np.float32)
+    rho[:, :, 0] = BIG                           # invalid barrier row
+    args = (xs, xb, kid, load, send, avail, rho)
+    want = lf.level_fold_jnp(*args, nl=nl, kcap=kcap)
+    got = lf.level_fold_pallas(*args, nl=nl, kcap=kcap, interpret=True)
+    assert got.shape == (B, W, nl, kcap)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+# ---------------------------------------------------------------------------
 # segment_reduce
 # ---------------------------------------------------------------------------
 
