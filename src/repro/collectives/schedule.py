@@ -12,6 +12,7 @@ import dataclasses
 
 import numpy as np
 
+from .. import telemetry
 from ..core.reduce import messages_up, messages_up_degraded, phi_degraded
 from ..core import baselines
 from ..engine.options import EngineOptions, resolve_options
@@ -503,71 +504,74 @@ def plan_fleet(fleet: Fleet, k: int,
     if not isinstance(fleet, Fleet):
         raise TypeError("plan_fleet needs a Fleet; wrap a single topology "
                         "with Fleet.single(topo)")
-    N = fleet.n_trees
-    if (loads is None) == (counts is None):
-        raise ValueError("pass exactly one of loads / counts")
-    if counts is not None:
-        if tree_of is not None:
-            raise ValueError("tree_of is derived from counts — pass it "
-                             "only with explicit loads")
-        counts = [int(c) for c in counts]
-        if len(counts) != N or any(c < 1 for c in counts):
-            raise ValueError(f"counts must give >=1 tenants for each of "
-                             f"the {N} trees, got {counts}")
-        tree_of = [g for g, c in enumerate(counts) for _ in range(c)]
-        loads = [fleet.topos[g].load for g in tree_of]
-    else:
-        if tree_of is None:
-            raise ValueError("explicit loads need tree_of (one tree index "
-                             "per tenant)")
-        tree_of = [int(g) for g in tree_of]
-        loads = list(loads)
-        if len(tree_of) != len(loads):
-            raise ValueError(f"{len(tree_of)} tree indices for "
-                             f"{len(loads)} loads")
-    T = len(loads)
-    tid = np.asarray(tree_of, np.int32)
-    if T and (tid.min() < 0 or tid.max() >= N):
-        raise ValueError(f"tree_of entries must be in [0, {N})")
-    if avails is not None:
-        avails = list(avails)
-        if len(avails) != T:
-            raise ValueError(f"{len(avails)} avail masks for {T} tenants — "
-                             "plan_fleet pairs them positionally")
-    else:
-        avails = [None] * T
-    # per-tree fault domains + mask validation at the boundary
-    avails = [fleet.topos[g].candidates(av)
-              for g, av in zip(tree_of, avails)]
-    if driver_kw.get("capacity") is not None:
-        caps = list(driver_kw["capacity"])
-        if len(caps) != N:
-            raise ValueError(f"{len(caps)} capacity vectors for {N} trees "
-                             "— plan_fleet takes one per tree")
-        driver_kw["capacity"] = [
-            _check_capacity(c, fleet.topos[g].tree.n, "plan_fleet")
-            * (np.clip(fleet.topos[g].cap_scale, 0.0, 1.0)
-               if fleet.topos[g].cap_scale is not None else 1.0)
-            for g, c in enumerate(caps)]
-    if driver_kw.get("residual") is not None:
-        resid = list(driver_kw["residual"])
-        if len(resid) != N:
-            raise ValueError(f"{len(resid)} residual ledgers for {N} trees "
-                             "— plan_fleet takes one per tree")
-        driver_kw["residual"] = [
-            _check_residual(rg, fleet.topos[g].tree.n, "plan_fleet")
-            for g, rg in enumerate(resid)]
+    with telemetry.span("engine.prepare"):
+        N = fleet.n_trees
+        if (loads is None) == (counts is None):
+            raise ValueError("pass exactly one of loads / counts")
+        if counts is not None:
+            if tree_of is not None:
+                raise ValueError("tree_of is derived from counts — pass it "
+                                 "only with explicit loads")
+            counts = [int(c) for c in counts]
+            if len(counts) != N or any(c < 1 for c in counts):
+                raise ValueError(f"counts must give >=1 tenants for each of "
+                                 f"the {N} trees, got {counts}")
+            tree_of = [g for g, c in enumerate(counts) for _ in range(c)]
+            loads = [fleet.topos[g].load for g in tree_of]
+        else:
+            if tree_of is None:
+                raise ValueError("explicit loads need tree_of (one tree index "
+                                 "per tenant)")
+            tree_of = [int(g) for g in tree_of]
+            loads = list(loads)
+            if len(tree_of) != len(loads):
+                raise ValueError(f"{len(tree_of)} tree indices for "
+                                 f"{len(loads)} loads")
+        T = len(loads)
+        tid = np.asarray(tree_of, np.int32)
+        if T and (tid.min() < 0 or tid.max() >= N):
+            raise ValueError(f"tree_of entries must be in [0, {N})")
+        if avails is not None:
+            avails = list(avails)
+            if len(avails) != T:
+                raise ValueError(f"{len(avails)} avail masks for {T} "
+                                 "tenants — plan_fleet pairs them "
+                                 "positionally")
+        else:
+            avails = [None] * T
+        # per-tree fault domains + mask validation at the boundary
+        avails = [fleet.topos[g].candidates(av)
+                  for g, av in zip(tree_of, avails)]
+        if driver_kw.get("capacity") is not None:
+            caps = list(driver_kw["capacity"])
+            if len(caps) != N:
+                raise ValueError(f"{len(caps)} capacity vectors for {N} trees "
+                                 "— plan_fleet takes one per tree")
+            driver_kw["capacity"] = [
+                _check_capacity(c, fleet.topos[g].tree.n, "plan_fleet")
+                * (np.clip(fleet.topos[g].cap_scale, 0.0, 1.0)
+                   if fleet.topos[g].cap_scale is not None else 1.0)
+                for g, c in enumerate(caps)]
+        if driver_kw.get("residual") is not None:
+            resid = list(driver_kw["residual"])
+            if len(resid) != N:
+                raise ValueError(f"{len(resid)} residual ledgers for {N} "
+                                 "trees — plan_fleet takes one per tree")
+            driver_kw["residual"] = [
+                _check_residual(rg, fleet.topos[g].tree.n, "plan_fleet")
+                for g, rg in enumerate(resid)]
     from ..engine import solve_fleet
     res = solve_fleet([tp.tree for tp in fleet.topos], loads, tid, k,
                       avails,
                       core_rho=fleet.core_rho if fleet.n_core else None,
                       core_path=fleet.core_path if fleet.n_core else None,
                       **driver_kw)
-    plans = []
-    for t, (L, g) in enumerate(zip(loads, tree_of, strict=True)):
-        tp = fleet.topos[g]
-        blue = res.blue[t, : tp.tree.n]
-        tenant_topo = dataclasses.replace(tp, load=np.asarray(L, np.int64))
-        prog = build_program(tenant_topo, blue)
-        plans.append(TenantPlan(blue, prog, prog.utilization))
+    with telemetry.span("schedule.build_programs"):
+        plans = []
+        for t, (L, g) in enumerate(zip(loads, tree_of, strict=True)):
+            tp = fleet.topos[g]
+            blue = res.blue[t, : tp.tree.n]
+            tenant_topo = dataclasses.replace(tp, load=np.asarray(L, np.int64))
+            prog = build_program(tenant_topo, blue)
+            plans.append(TenantPlan(blue, prog, prog.utilization))
     return FleetPlan(plans, res, tid)

@@ -34,6 +34,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import telemetry
 from .tree import DEST, Tree
 
 
@@ -160,12 +161,6 @@ def _bucket_up(x: int) -> int:
     return x if x <= 1 else 1 << (x - 1).bit_length()
 
 
-# jit-cache telemetry: how many forests were packed, and how many *distinct*
-# compiled layouts those forests map to (see :func:`layout_key`).
-_LAYOUTS_SEEN: set[tuple] = set()
-_FORESTS_BUILT: int = 0
-
-
 def layout_key(f: Forest) -> tuple:
     """The static part of the engine's jit key for this forest.
 
@@ -177,11 +172,13 @@ def layout_key(f: Forest) -> tuple:
 
 
 def layout_stats() -> dict:
-    """Packing-side cache telemetry: forests built vs distinct jit layouts."""
-    return {"forests_built": _FORESTS_BUILT,
-            "distinct_layouts": len(_LAYOUTS_SEEN)}
+    """Packing-side cache telemetry: forests built vs distinct jit layouts
+    (the ``engine.forests_built`` and ``engine.layouts`` counters)."""
+    return {"forests_built": int(telemetry.get("engine.forests_built")),
+            "distinct_layouts": int(telemetry.get("engine.layouts"))}
 
 
+@telemetry.traced("engine.pack")
 def build_forest(
     trees: Sequence[Tree],
     loads: Sequence[np.ndarray],
@@ -337,9 +334,8 @@ def build_forest(
                pk_rho_up=pk_rho_up, lvl_off=tuple(lvl_off),
                lvl_width=tuple(lvl_width),
                lvl_internal=tuple(lvl_internal), lvl_sub=tuple(lvl_sub))
-    global _FORESTS_BUILT
-    _FORESTS_BUILT += 1
-    _LAYOUTS_SEEN.add(layout_key(f))
+    telemetry.count("engine.forests_built")
+    telemetry.count_distinct("engine.layouts", layout_key(f))
     return f
 
 
@@ -377,6 +373,7 @@ class FleetLayout:
         return self.core_offset + self.n_core
 
 
+@telemetry.traced("engine.pack")
 def build_fleet_forest(
     trees: Sequence[Tree],
     loads: Sequence[np.ndarray],

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..core.forest import Forest, build_forest, layout_stats
 from ..core.tree import Tree
 from ..core.tropical import BIG, minplus_batch
@@ -133,11 +134,12 @@ def _gather_packed(
             xb = jnp.concatenate(
                 [ch[:, :, 1, :Kd], jnp.zeros((B, 1, Kd), dt)], axis=1)
             kid_local = jnp.minimum(pk_kid[:, o : o + Wi] - o1, W1)
-            out = level_fold(
-                xs, xb, kid_local, loadf[:, o : o + Wi],
-                sendf[:, o : o + Wi], pk_avail[:, o : o + Wi],
-                pk_rho_up[:, o : o + Wi, :nl], nl=nl, kcap=Kd,
-                use_pallas=use_pallas, interpret=interpret)
+            with jax.named_scope("levelfold"):
+                out = level_fold(
+                    xs, xb, kid_local, loadf[:, o : o + Wi],
+                    sendf[:, o : o + Wi], pk_avail[:, o : o + Wi],
+                    pk_rho_up[:, o : o + Wi, :nl], nl=nl, kcap=Kd,
+                    use_pallas=use_pallas, interpret=interpret)
             if Kd < K:                                 # flat-pad (monotone)
                 out = jnp.concatenate(
                     [out, jnp.broadcast_to(out[..., -1:],
@@ -159,6 +161,7 @@ def _gather_packed(
     return tuple(blocks)
 
 
+@jax.named_scope("color")
 def _color_body(
     blocks: tuple,         # per-level gather blocks, see _gather_packed
     pk_kid: jax.Array,     # (B, S, max_c) int32 child slots, sentinel S
@@ -360,6 +363,7 @@ def _color_packed(
 _INPUT_CACHE: dict[tuple, tuple] = {}
 
 
+@telemetry.traced("engine.upload")
 def _device_inputs(f: Forest, dtype) -> tuple:
     """One host->device upload of the packed arrays (shared gather/color).
 
@@ -371,11 +375,13 @@ def _device_inputs(f: Forest, dtype) -> tuple:
     The cache assumes built Forests are immutable — mutating a Forest's
     numpy arrays in place after a solve would silently reuse the stale
     device copies; rebuild via :func:`build_forest` instead (cheap: the
-    per-tree structure is itself cached).
+    per-tree structure is itself cached). Counts ``engine.upload_hits``,
+    and on a miss the device bytes written in ``engine.upload_bytes``.
     """
     key = (id(f), np.dtype(dtype).str)
     hit = _INPUT_CACHE.get(key)
     if hit is not None and hit[0]() is f:
+        telemetry.count("engine.upload_hits")
         return hit[1]
     R = jnp.asarray(np.where(np.isfinite(f.pk_rho_up), f.pk_rho_up, BIG),
                     dtype)
@@ -384,6 +390,7 @@ def _device_inputs(f: Forest, dtype) -> tuple:
               jnp.asarray(f.pk_par), jnp.asarray(f.pk_cidx),
               jnp.asarray(f.slot_of),
               jnp.asarray(f.slot_of[np.arange(f.batch), f.root]))
+    telemetry.count("engine.upload_bytes", sum(x.nbytes for x in inputs))
     _INPUT_CACHE[key] = (weakref.ref(f, lambda _, k=key:
                                      _INPUT_CACHE.pop(k, None)), inputs)
     return inputs
@@ -392,6 +399,7 @@ def _device_inputs(f: Forest, dtype) -> tuple:
 _OVERRIDE_CACHE: dict[tuple, tuple] = {}
 
 
+@telemetry.traced("engine.upload")
 def _override_inputs(f: Forest, dtype) -> tuple:
     """Device arrays for re-solving ``f`` under effective-rho overrides.
 
@@ -610,27 +618,15 @@ class BatchResult:
         return self.blue[b, : int(self.n[b])]
 
 
-def _jit_cache_size(fn) -> int:
-    try:
-        return int(fn._cache_size())
-    except Exception:  # pragma: no cover - private API drift across jax
-        return -1
-
-
 def cache_stats() -> dict:
-    """Engine compile-cache telemetry.
-
-    ``gather_cache`` / ``color_cache`` count compiled executables held by
-    the two jitted sweeps; ``forests_built`` / ``distinct_layouts`` are
-    packing-side counts from :func:`repro.core.forest.layout_stats` —
-    with layout bucketing on, ``distinct_layouts`` (and hence the jit
-    caches) stays far below ``forests_built`` on ragged fleets.
+    """Engine packing-cache telemetry: ``forests_built`` /
+    ``distinct_layouts`` from :func:`repro.core.forest.layout_stats` — with
+    layout bucketing on, ``distinct_layouts`` (and hence the compiled
+    executables) stays far below ``forests_built`` on ragged fleets. Whether
+    serving recompiles is what
+    :func:`repro.launch.compile_cache.compile_stats` counts.
     """
-    return {
-        "gather_cache": _jit_cache_size(_gather_packed),
-        "color_cache": _jit_cache_size(_color_packed),
-        **layout_stats(),
-    }
+    return layout_stats()
 
 
 def solve_forest(
@@ -668,6 +664,13 @@ def solve_forest(
     rates extend the root edge additively rather than multiplicatively.
     """
     opts = resolve_options(options, engine_kw, "solve_forest")
+    with telemetry.span("engine.solve", call=telemetry.count("engine.solves")):
+        return _solve_forest(f, k, opts, rho_scale, rho_root_add)
+
+
+def _solve_forest(f: Forest, k: int, opts: EngineOptions,
+                  rho_scale=None, rho_root_add=None) -> BatchResult:
+    """:func:`solve_forest` with resolved options and no span of its own."""
     if k < 0:
         raise ValueError("budget k must be non-negative")
     use_pallas = pallas_fold(opts)
@@ -701,8 +704,9 @@ def solve_forest(
     kid_d, load_d, send_d, avail_d, R, par_d, cidx_d, slot_d, root_d = inputs
     if not opts.color:
         # costs-only planning mode: pull back B scalars, not the tables
-        roots = np.asarray(
-            blocks[0][jnp.arange(f.batch), root_d - f.lvl_off[0], 1, k])
+        with telemetry.span("engine.readback"):
+            roots = np.asarray(
+                blocks[0][jnp.arange(f.batch), root_d - f.lvl_off[0], 1, k])
         return BatchResult(blue=None, costs=roots.astype(np.float64),
                            n=f.n.copy(), bytes_to_host=int(roots.nbytes))
     if opts.debug_tables:
@@ -711,14 +715,15 @@ def solve_forest(
         return BatchResult(blue=color_batch(f, Xn, k), costs=costs,
                            n=f.n.copy(), tables=Xn,
                            bytes_to_host=sum(int(b.nbytes) for b in blocks))
-    blue_dev, costs_dev = _color_packed(
-        blocks, kid_d, par_d, cidx_d, load_d, send_d, avail_d, R,
-        root_d, slot_d,
-        lvl_off=f.lvl_off, lvl_width=f.lvl_width,
-        lvl_internal=f.lvl_internal, lvl_sub=f.lvl_sub, k=k,
-        cap=bool(opts.cap))
-    blue = np.asarray(blue_dev)
-    costs = np.asarray(costs_dev)
+    with telemetry.span("engine.readback"):
+        blue_dev, costs_dev = _color_packed(
+            blocks, kid_d, par_d, cidx_d, load_d, send_d, avail_d, R,
+            root_d, slot_d,
+            lvl_off=f.lvl_off, lvl_width=f.lvl_width,
+            lvl_internal=f.lvl_internal, lvl_sub=f.lvl_sub, k=k,
+            cap=bool(opts.cap))
+        blue = np.asarray(blue_dev)
+        costs = np.asarray(costs_dev)
     return BatchResult(blue=blue, costs=costs.astype(np.float64),
                        n=f.n.copy(),
                        bytes_to_host=int(blue.nbytes + costs.nbytes))
@@ -745,4 +750,5 @@ def solve_batch(
     :func:`solve_forest`.
     """
     opts = resolve_options(options, engine_kw, "solve_batch")
-    return solve_forest(build_forest(trees, loads, avail), k, options=opts)
+    with telemetry.span("engine.solve", call=telemetry.count("engine.solves")):
+        return _solve_forest(build_forest(trees, loads, avail), k, opts)

@@ -100,6 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..core.congestion import _messages_body, measure_fleet_multi
 from ..core.forest import build_fleet_forest, build_forest
 from ..core.tree import Tree
@@ -349,6 +350,7 @@ def _device_driver(
     dt = base_edge.dtype
     C = core_base.shape[0]
 
+    @jax.named_scope("penalty_round")
     def body(carry):
         (r, w, wc, avail, stale, stop, best_cmax, best_blue, best_round,
          best_drop, history, prof0, prof0c, log_rho, log_blue,
@@ -478,159 +480,163 @@ def solve_fleet(
     :func:`solve_congestion`, which is the degenerate ``N=1, C=0`` call
     of this driver.
     """
-    T = len(loads)
-    if T == 0:
-        raise ValueError("solve_fleet needs at least one tenant")
-    if max_rounds < 1:
-        raise ValueError("max_rounds must be >= 1")
-    opts = resolve_options(options, engine_kw, "solve_fleet")
-    if not opts.color:
-        raise ValueError("solve_fleet needs blue masks; color=False "
-                         "(costs-only mode) is not usable here")
-    if opts.debug_tables:
-        raise ValueError("solve_fleet re-solves on device-side effective "
-                         "rho; the debug_tables host replay is not usable "
-                         "here")
-    # capacity-knob boundary validation: _crowding clamps capacity with
-    # 1e-6 (a numerical guard, not a semantics), so malformed knobs must
-    # die here, not price a zero-capacity switch as admittable
-    if not (np.isfinite(cap_frac) and 0.0 < cap_frac <= 1.0):
-        raise ValueError(f"cap_frac must be in (0, 1], got {cap_frac}")
-    if not (np.isfinite(cap_beta) and cap_beta >= 0.0):
-        raise ValueError(f"cap_beta must be finite and >= 0, "
-                         f"got {cap_beta}")
-    trees = list(trees)
-    N = len(trees)
-    tid_np = np.asarray(list(tree_of), np.int32)
-    if tid_np.shape != (T,):
-        raise ValueError(f"tree_of shape {tid_np.shape} != ({T},)")
-    if avail is None:
-        avails = [None] * T
-    else:
-        avails = list(avail)
-        if len(avails) != T:
-            raise ValueError(f"{len(avails)} avail masks for {T} tenants")
-    priced = capacity is not None
-    if priced:
-        capacity = [np.asarray(c, np.float64) for c in capacity]
-        if len(capacity) != N:
-            raise ValueError(f"{len(capacity)} capacity vectors for "
-                             f"{N} trees")
-        for g, c in enumerate(capacity):
-            if c.shape != (trees[g].n,):
-                raise ValueError(f"capacity shape {c.shape} != "
-                                 f"({trees[g].n},)")
-            if not np.all(np.isfinite(c)) or np.any(c < 0):
-                raise ValueError(f"capacity vector for tree {g} must be "
-                                 "finite and non-negative")
-    admit = residual is not None
-    if admit:
-        residual = [np.asarray(rg) for rg in residual]
-        if len(residual) != N:
-            raise ValueError(f"{len(residual)} residual ledgers for "
-                             f"{N} trees")
-        checked = []
-        for g, rg in enumerate(residual):
-            if rg.shape != (trees[g].n,):
-                raise ValueError(f"residual shape {rg.shape} != "
-                                 f"({trees[g].n},) for tree {g}")
-            if (not np.all(np.isfinite(rg.astype(np.float64)))
-                    or np.any(rg.astype(np.float64)
-                              != np.floor(rg.astype(np.float64)))):
-                raise ValueError(f"residual ledger for tree {g} must be "
-                                 "integer-valued")
-            if np.any(rg.astype(np.int64) < 0):
-                raise ValueError(f"residual ledger for tree {g} must be "
-                                 "non-negative")
-            checked.append(rg.astype(np.int64))
-        residual = checked
-    if admit or priced:
-        # hard-unavailability flows through the avail mechanics: switches
-        # with no residual (or no capacity at all) leave their tree's
-        # tenants' candidate sets before the first solve
-        hard = [np.ones(tr.n, bool) for tr in trees]
-        for g in range(N):
-            if admit:
-                hard[g] &= residual[g] > 0
-            if priced:
-                hard[g] &= capacity[g] > 0
-        if not all(h.all() for h in hard):
-            avails = [
-                (hard[g].copy() if a is None
-                 else np.asarray(a, bool) & hard[g])
-                for a, g in zip(avails, tid_np)]
-    if admit:
-        # the host ledger replay mutates its per-tenant masks (persistent
-        # bans) — every tenant needs its own materialized copy
-        avails = [np.ones(trees[g].n, bool) if a is None
-                  else np.array(a, dtype=bool, copy=True)
-                  for a, g in zip(avails, tid_np)]
-    use_pallas = pallas_fold(opts)
+    telemetry.count("engine.solves")
+    with telemetry.span("engine.prepare"):
+        T = len(loads)
+        if T == 0:
+            raise ValueError("solve_fleet needs at least one tenant")
+        if max_rounds < 1:
+            raise ValueError("max_rounds must be >= 1")
+        opts = resolve_options(options, engine_kw, "solve_fleet")
+        if not opts.color:
+            raise ValueError("solve_fleet needs blue masks; color=False "
+                             "(costs-only mode) is not usable here")
+        if opts.debug_tables:
+            raise ValueError("solve_fleet re-solves on device-side effective "
+                             "rho; the debug_tables host replay is not usable "
+                             "here")
+        # capacity-knob boundary validation: _crowding clamps capacity with
+        # 1e-6 (a numerical guard, not a semantics), so malformed knobs must
+        # die here, not price a zero-capacity switch as admittable
+        if not (np.isfinite(cap_frac) and 0.0 < cap_frac <= 1.0):
+            raise ValueError(f"cap_frac must be in (0, 1], got {cap_frac}")
+        if not (np.isfinite(cap_beta) and cap_beta >= 0.0):
+            raise ValueError(f"cap_beta must be finite and >= 0, "
+                             f"got {cap_beta}")
+        trees = list(trees)
+        N = len(trees)
+        tid_np = np.asarray(list(tree_of), np.int32)
+        if tid_np.shape != (T,):
+            raise ValueError(f"tree_of shape {tid_np.shape} != ({T},)")
+        if avail is None:
+            avails = [None] * T
+        else:
+            avails = list(avail)
+            if len(avails) != T:
+                raise ValueError(f"{len(avails)} avail masks for {T} tenants")
+        priced = capacity is not None
+        if priced:
+            capacity = [np.asarray(c, np.float64) for c in capacity]
+            if len(capacity) != N:
+                raise ValueError(f"{len(capacity)} capacity vectors for "
+                                 f"{N} trees")
+            for g, c in enumerate(capacity):
+                if c.shape != (trees[g].n,):
+                    raise ValueError(f"capacity shape {c.shape} != "
+                                     f"({trees[g].n},)")
+                if not np.all(np.isfinite(c)) or np.any(c < 0):
+                    raise ValueError(f"capacity vector for tree {g} must be "
+                                     "finite and non-negative")
+        admit = residual is not None
+        if admit:
+            residual = [np.asarray(rg) for rg in residual]
+            if len(residual) != N:
+                raise ValueError(f"{len(residual)} residual ledgers for "
+                                 f"{N} trees")
+            checked = []
+            for g, rg in enumerate(residual):
+                if rg.shape != (trees[g].n,):
+                    raise ValueError(f"residual shape {rg.shape} != "
+                                     f"({trees[g].n},) for tree {g}")
+                if (not np.all(np.isfinite(rg.astype(np.float64)))
+                        or np.any(rg.astype(np.float64)
+                                  != np.floor(rg.astype(np.float64)))):
+                    raise ValueError(f"residual ledger for tree {g} must be "
+                                     "integer-valued")
+                if np.any(rg.astype(np.int64) < 0):
+                    raise ValueError(f"residual ledger for tree {g} must be "
+                                     "non-negative")
+                checked.append(rg.astype(np.int64))
+            residual = checked
+        if admit or priced:
+            # hard-unavailability flows through the avail mechanics: switches
+            # with no residual (or no capacity at all) leave their tree's
+            # tenants' candidate sets before the first solve
+            hard = [np.ones(tr.n, bool) for tr in trees]
+            for g in range(N):
+                if admit:
+                    hard[g] &= residual[g] > 0
+                if priced:
+                    hard[g] &= capacity[g] > 0
+            if not all(h.all() for h in hard):
+                avails = [
+                    (hard[g].copy() if a is None
+                     else np.asarray(a, bool) & hard[g])
+                    for a, g in zip(avails, tid_np)]
+        if admit:
+            # the host ledger replay mutates its per-tenant masks (persistent
+            # bans) — every tenant needs its own materialized copy
+            avails = [np.ones(trees[g].n, bool) if a is None
+                      else np.array(a, dtype=bool, copy=True)
+                      for a, g in zip(avails, tid_np)]
+        use_pallas = pallas_fold(opts)
 
     # one Forest, one packing, one compiled executable for the whole loop
     f, lay = build_fleet_forest(trees, list(loads), tid_np, avails,
                                 core_rho=core_rho, core_path=core_path)
-    C = lay.n_core
-    dt = opts.dtype
-    kid, load, send, avail_d, _, par, cidx, slot_d, root_d = \
-        _device_inputs(f, dt)
-    base_edge, anc, valid, _, _ = _override_inputs(f, dt)
-    rep = lay.rep
+    with telemetry.span("engine.upload"):
+        C = lay.n_core
+        dt = opts.dtype
+        kid, load, send, avail_d, _, par, cidx, slot_d, root_d = \
+            _device_inputs(f, dt)
+        base_edge, anc, valid, _, _ = _override_inputs(f, dt)
+        rep = lay.rep
 
-    # per-tenant penalty ramp: deterministic symmetry breaker
-    ramp_t = jnp.asarray(
-        (1.0 + np.arange(T) / max(1, T - 1))[:, None], dt)
-    alpha_t = jnp.asarray(alpha, dt) * ramp_t
-    scal = dict(hot_frac=jnp.asarray(hot_frac, dt),
-                w_cap=jnp.asarray(w_cap, dt),
-                cap_beta=jnp.asarray(cap_beta, dt),
-                cap_frac=jnp.asarray(cap_frac, dt))
-    # per-tree node-indexed per-link constants (host reference) and their
-    # slot-indexed twins (device loop) — same value per real link, so the
-    # two paths' elementwise updates agree bitwise
-    if rho_weighted:
-        link_w_node = np.zeros((N, f.n_max))
-        for g, tr in enumerate(trees):
-            link_w_node[g, : tr.n] = tr.rho
-        link_w_node = jnp.asarray(link_w_node, dt)
-        link_w_slot = base_edge[jnp.asarray(rep)]          # (N, S)
-        core_link_w = jnp.asarray(lay.core_rho, dt)
-    else:
-        link_w_node = jnp.ones((N, f.n_max), dt)
-        link_w_slot = jnp.ones((N, f.n_slots), dt)
-        core_link_w = jnp.ones((C,), dt)
-    cap_node = np.ones((N, f.n_max))
-    cap_slot = np.ones((N, f.n_slots))
-    if priced:
-        for g in range(N):
-            cap_node[g, : trees[g].n] = capacity[g]
-            sn_g = f.slot_node[rep[g]]
-            cap_slot[g] = np.where(sn_g >= 0,
-                                   cap_node[g][np.maximum(sn_g, 0)], 1.0)
-    cap_node = jnp.asarray(cap_node, dt)
-    cap_slot = jnp.asarray(cap_slot, dt)
-    # residual ledger twins (node for the host replay, slot for the device
-    # rank truncation) — padding slots read T so they can never reject
-    res_slot_np = np.full((N, f.n_slots), T, np.int64)
-    if admit:
-        res_node_np = np.zeros((N, f.n_max), np.int64)
-        for g in range(N):
-            res_node_np[g, : trees[g].n] = residual[g]
-            sn_g = f.slot_node[rep[g]]
-            res_slot_np[g] = np.where(
-                sn_g >= 0, res_node_np[g][np.maximum(sn_g, 0)], T)
-    res_slot = jnp.asarray(res_slot_np, jnp.int32)
-    tree_id = jnp.asarray(lay.tree_of)
-    core_base = jnp.asarray(lay.core_rho, dt)              # (C,)
-    core_on = jnp.asarray(lay.core_inc)                    # (T, C) bool
+        # per-tenant penalty ramp: deterministic symmetry breaker
+        ramp_t = jnp.asarray(
+            (1.0 + np.arange(T) / max(1, T - 1))[:, None], dt)
+        alpha_t = jnp.asarray(alpha, dt) * ramp_t
+        scal = dict(hot_frac=jnp.asarray(hot_frac, dt),
+                    w_cap=jnp.asarray(w_cap, dt),
+                    cap_beta=jnp.asarray(cap_beta, dt),
+                    cap_frac=jnp.asarray(cap_frac, dt))
+        # per-tree node-indexed per-link constants (host reference) and their
+        # slot-indexed twins (device loop) — same value per real link, so the
+        # two paths' elementwise updates agree bitwise
+        if rho_weighted:
+            link_w_node = np.zeros((N, f.n_max))
+            for g, tr in enumerate(trees):
+                link_w_node[g, : tr.n] = tr.rho
+            link_w_node = jnp.asarray(link_w_node, dt)
+            link_w_slot = base_edge[jnp.asarray(rep)]          # (N, S)
+            core_link_w = jnp.asarray(lay.core_rho, dt)
+        else:
+            link_w_node = jnp.ones((N, f.n_max), dt)
+            link_w_slot = jnp.ones((N, f.n_slots), dt)
+            core_link_w = jnp.ones((C,), dt)
+        cap_node = np.ones((N, f.n_max))
+        cap_slot = np.ones((N, f.n_slots))
+        if priced:
+            for g in range(N):
+                cap_node[g, : trees[g].n] = capacity[g]
+                sn_g = f.slot_node[rep[g]]
+                cap_slot[g] = np.where(sn_g >= 0,
+                                       cap_node[g][np.maximum(sn_g, 0)], 1.0)
+        cap_node = jnp.asarray(cap_node, dt)
+        cap_slot = jnp.asarray(cap_slot, dt)
+        # residual ledger twins (node for the host replay, slot for the device
+        # rank truncation) — padding slots read T so they can never reject
+        res_slot_np = np.full((N, f.n_slots), T, np.int64)
+        if admit:
+            res_node_np = np.zeros((N, f.n_max), np.int64)
+            for g in range(N):
+                res_node_np[g, : trees[g].n] = residual[g]
+                sn_g = f.slot_node[rep[g]]
+                res_slot_np[g] = np.where(
+                    sn_g >= 0, res_node_np[g][np.maximum(sn_g, 0)], T)
+        res_slot = jnp.asarray(res_slot_np, jnp.int32)
+        tree_id = jnp.asarray(lay.tree_of)
+        core_base = jnp.asarray(lay.core_rho, dt)              # (C,)
+        core_on = jnp.asarray(lay.core_inc)                    # (T, C) bool
 
     if device_loop:
-        state = _run_device(f, lay, k, opts, use_pallas, kid, load, send,
-                            avail_d, par, cidx, root_d, base_edge, anc,
-                            valid, tree_id, link_w_slot, cap_slot, res_slot,
-                            core_base, core_on, core_link_w, alpha_t,
-                            ramp_t, scal, patience, max_rounds,
-                            record_rounds, priced, admit)
+        with telemetry.span("engine.loop"):
+            state = _run_device(f, lay, k, opts, use_pallas, kid, load,
+                                send, avail_d, par, cidx, root_d, base_edge,
+                                anc, valid, tree_id, link_w_slot, cap_slot,
+                                res_slot, core_base, core_on, core_link_w,
+                                alpha_t, ramp_t, scal, patience, max_rounds,
+                                record_rounds, priced, admit)
     else:
         state = _run_host(trees, loads, tid_np, avails, f, lay, k, opts,
                           link_w_node, cap_node, residual, core_base,
@@ -640,34 +646,35 @@ def solve_fleet(
     (blue_node, best_round, rounds, history, prof0_node, prof0_core,
      rounds_log, bytes_to_host, best_drop, admission_log) = state
 
-    n_big = int(lay.tree_n.max())
-    blue = blue_node[:, :n_big]
-    # the reported statistics come from the one shared measurement recipe
-    # (measure_fleet_multi — same code path the orchestrator's
-    # post-admission re-measure uses); its host sweep is bit-identical to
-    # the device messages the loop tracked, so nothing shifts in the
-    # hand-off
-    m = measure_fleet_multi(
-        trees, tid_np, list(loads),
-        [blue[t, : trees[int(tid_np[t])].n] for t in range(T)],
-        core_rho=lay.core_rho if C else None,
-        core_path=lay.core_path if C else None,
-        rho_weighted=rho_weighted)
-    parts = [prof0_node[g, : trees[g].n] for g in range(N)]
-    if C:
-        parts.append(prof0_core)
-    base0 = np.concatenate(parts)
-    base0 = base0[base0 > 0]
-    admission_dropped = residual_after = None
-    if admit:
-        admission_dropped = np.asarray(best_drop, np.int64)
-        residual_after = []
-        for g in range(N):
-            claims = np.zeros(trees[g].n, np.int64)
-            for t in range(T):
-                if int(tid_np[t]) == g:
-                    claims += blue[t, : trees[g].n].astype(np.int64)
-            residual_after.append(residual[g] - claims)
+    with telemetry.span("engine.remeasure"):
+        n_big = int(lay.tree_n.max())
+        blue = blue_node[:, :n_big]
+        # the reported statistics come from the one shared measurement recipe
+        # (measure_fleet_multi — same code path the orchestrator's
+        # post-admission re-measure uses); its host sweep is bit-identical to
+        # the device messages the loop tracked, so nothing shifts in the
+        # hand-off
+        m = measure_fleet_multi(
+            trees, tid_np, list(loads),
+            [blue[t, : trees[int(tid_np[t])].n] for t in range(T)],
+            core_rho=lay.core_rho if C else None,
+            core_path=lay.core_path if C else None,
+            rho_weighted=rho_weighted)
+        parts = [prof0_node[g, : trees[g].n] for g in range(N)]
+        if C:
+            parts.append(prof0_core)
+        base0 = np.concatenate(parts)
+        base0 = base0[base0 > 0]
+        admission_dropped = residual_after = None
+        if admit:
+            admission_dropped = np.asarray(best_drop, np.int64)
+            residual_after = []
+            for g in range(N):
+                claims = np.zeros(trees[g].n, np.int64)
+                for t in range(T):
+                    if int(tid_np[t]) == g:
+                        claims += blue[t, : trees[g].n].astype(np.int64)
+                residual_after.append(residual[g] - claims)
     return CongestionResult(
         blue=blue, costs=m.costs, msgs=m.msgs, congestion=m.congestion,
         max_congestion=m.max_congestion,
@@ -794,7 +801,8 @@ def _run_device(f, lay, k, opts, use_pallas, kid, load, send, avail_d, par,
                 cap_slot, res_slot, core_base, core_on, core_link_w, alpha_t,
                 ramp_t, scal, patience, max_rounds, record_rounds, priced,
                 admit):
-    """Dispatch the resident loop; pull the final state once."""
+    """Dispatch the resident loop; pull the final state once. Counts the
+    loop in ``penalty.loops`` and its rounds in ``penalty.rounds``."""
     n_big = int(lay.tree_n.max())
     out = _device_driver(
         kid, load, send, avail_d, par, cidx, root_d,
@@ -816,6 +824,8 @@ def _run_device(f, lay, k, opts, use_pallas, kid, load, send, avail_d, par,
                          prof0_s, prof0c_d, best_drop_d, log_rho, log_blue,
                          log_drop))
     rounds = int(rounds_d)
+    telemetry.count("penalty.loops")
+    telemetry.count("penalty.rounds", rounds)
     best_round = int(best_round_d)
     history = [float(c) for c in hist_d[:rounds]]
     blue_node = _slots_to_nodes_np(best_blue_s, f)

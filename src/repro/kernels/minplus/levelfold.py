@@ -339,6 +339,7 @@ def level_fold_pallas(xs, xb, kid, load, send, avail, rho, *, nl: int,
                         pltpu.VMEM((2 * kcap - 1, *tl), dt)],
         compiler_params=pltpu.CompilerParams(**params),
         interpret=interpret,
+        name="levelfold",
     )(xr, xbb, *vec, rl)
     out = out.reshape(nl, kcap, pr * LANE)[:, :, :P]
     return jnp.transpose(out.reshape(nl, kcap, B, W), (2, 3, 0, 1))

@@ -12,8 +12,9 @@ import), before the first compile:
 Every program is cached, however quick its compile. The listeners count
 cache hits and misses and add up compile seconds (lowering to MLIR plus
 the backend compile or cache read; tracing is left out, since nested
-jits trace inside their caller's span), which :func:`compile_stats`
-reports.
+jits trace inside their caller's span) into the ``compile.hits``,
+``compile.misses`` and ``compile.seconds`` counters of
+:mod:`repro.telemetry`, which :func:`compile_stats` reports.
 """
 from __future__ import annotations
 
@@ -22,10 +23,10 @@ from pathlib import Path
 
 import jax
 
-_COUNTS = {"hits": 0, "misses": 0}
-_SECONDS = {"compile_s": 0.0}
-_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
-           "/jax/compilation_cache/cache_misses": "misses"}
+from .. import telemetry
+
+_EVENTS = {"/jax/compilation_cache/cache_hits": "compile.hits",
+           "/jax/compilation_cache/cache_misses": "compile.misses"}
 _DURATIONS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
               "/jax/core/compile/backend_compile_duration")
 _installed = False
@@ -33,12 +34,12 @@ _installed = False
 
 def _on_event(event: str, **_) -> None:
     if event in _EVENTS:
-        _COUNTS[_EVENTS[event]] += 1
+        telemetry.count(_EVENTS[event])
 
 
 def _on_duration(event: str, duration: float, **_) -> None:
     if event in _DURATIONS:
-        _SECONDS["compile_s"] += duration
+        telemetry.count("compile.seconds", duration)
 
 
 def enable_compile_cache(root: str | os.PathLike) -> str:
@@ -58,4 +59,6 @@ def enable_compile_cache(root: str | os.PathLike) -> str:
 
 def compile_stats() -> dict:
     """Cache hits and misses and compile seconds since start-up."""
-    return {**_COUNTS, **_SECONDS}
+    return {"hits": int(telemetry.get("compile.hits")),
+            "misses": int(telemetry.get("compile.misses")),
+            "compile_s": float(telemetry.get("compile.seconds"))}

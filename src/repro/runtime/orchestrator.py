@@ -35,6 +35,7 @@ import dataclasses
 
 import numpy as np
 
+from .. import telemetry
 from ..collectives.schedule import (ReduceProgram, build_program, plan,
                                     plan_batch, plan_congestion, plan_fleet)
 from ..collectives.topology import (ClusterTopology, Fleet, degrade_links,
@@ -659,6 +660,7 @@ class Orchestrator:
         self.jobs[rec.job_id] = rec
         return rec
 
+    @telemetry.traced("orchestrator.release")
     def release_workloads(self, job_ids) -> int:
         """Release admitted jobs' capacity claims; returns claims freed."""
         freed = 0
@@ -765,93 +767,97 @@ class Orchestrator:
         switches (instantly, no solve) and re-solves once. Telemetry of
         every wave lands in ``self.last_admission``.
         """
-        if self._residual is None:
-            raise ValueError("begin_workloads needs capacity set")
-        if congestion_aware and self.cfg.strategy != "soar":
-            raise ValueError("congestion-aware admission needs "
-                             f"strategy='soar', not {self.cfg.strategy!r}")
-        if not congestion_aware and (driver_kw or capacity_priced
-                                     or device_admission):
-            what = (sorted(driver_kw) if driver_kw else
-                    "device_admission" if device_admission
-                    else "capacity_priced")
-            raise ValueError(f"driver options {what} only "
-                             "apply with congestion_aware=True")
-        if preemption is not None and not device_admission:
-            raise ValueError("preemption= needs device_admission=True — "
-                             "only the in-loop admission path reports the "
-                             "shortfall preemption resolves")
-        if device_admission and "residual" in driver_kw:
-            raise ValueError("device_admission=True supplies the "
-                             "orchestrator's residual ledger; don't also "
-                             "pass residual= explicitly")
-        if (count is None) == (fleet is None):
-            raise ValueError("pass exactly one of count / fleet")
-        if fleet is not None:
-            if not congestion_aware:
-                raise ValueError("fleet admission is congestion-coupled; "
-                                 "pass congestion_aware=True")
-            return self._begin_fleet_workloads(
-                [int(c) for c in fleet], capacity_priced, driver_kw,
-                device_admission=device_admission, preemption=preemption,
-                priority=priority)
-        if capacity_priced:
-            if "capacity" in driver_kw:
-                raise ValueError("capacity_priced=True supplies the "
-                                 "orchestrator's residual-capacity snapshot; "
-                                 "don't also pass capacity= explicitly")
-            driver_kw = dict(driver_kw,
-                             capacity=self._residual.astype(np.float64))
-        if count == 0:
-            return []
-        if device_admission:
-            return self._begin_device_admission(count, preemption, priority,
-                                                driver_kw)
-        snapshot = self._avail()
-        driver_res = None
-        if congestion_aware:
-            planned, driver_res = plan_congestion(
-                self.topo, self.cfg.k, count=count, avails=snapshot,
-                **driver_kw)
-        else:
-            planned = plan_batch([self.topo] * count, self.cfg.k,
-                                 [snapshot] * count,
-                                 strategy=self.cfg.strategy)
-        progs: list[ReduceProgram] = []
-        admitted: list[np.ndarray] = []
-        collisions = 0
-        for blue, prog in planned:
-            if np.any(blue & (self._residual <= 0)):   # capacity collision
-                blue, prog = plan(self.topo, self.cfg.k, avail=self._avail(),
-                                  strategy=self.cfg.strategy)
-                collisions += 1
-            self._residual[blue] -= 1
-            self.utilization_history.append(prog.utilization)
-            self._register_job(blue, prog, priority=priority)
-            progs.append(prog)
-            admitted.append(blue)
-        # each collision fallback is one extra host-side solve round trip
-        # on top of the wave's batched solve
-        self.last_admission = {
-            "path": "host", "solves": 1 + collisions,
-            "round_trips": 1 + collisions, "collisions": collisions,
-            "dropped": 0, "preempted": (), "cache_hit": False}
-        if driver_res is not None:
-            # collision fallbacks replace driver placements with
-            # utilization-only ones; re-measure so last_congestion reports
-            # what was actually admitted, not what the driver proposed
-            if collisions:
-                from ..core.congestion import measure_fleet
-                m = measure_fleet(
-                    self.topo.tree, [self.topo.load] * count, admitted,
-                    rho_weighted=driver_kw.get("rho_weighted", False))
-                driver_res = dataclasses.replace(
-                    driver_res, blue=np.stack(admitted), costs=m.costs,
-                    msgs=m.msgs, congestion=m.congestion,
-                    max_congestion=m.max_congestion,
-                    mean_congestion=m.mean_congestion)
-            self.last_congestion = driver_res
-        return progs
+        with telemetry.span("orchestrator.admit",
+                            call=telemetry.count("orchestrator.waves")):
+            if self._residual is None:
+                raise ValueError("begin_workloads needs capacity set")
+            if congestion_aware and self.cfg.strategy != "soar":
+                raise ValueError("congestion-aware admission needs "
+                                 f"strategy='soar', not {self.cfg.strategy!r}")
+            if not congestion_aware and (driver_kw or capacity_priced
+                                         or device_admission):
+                what = (sorted(driver_kw) if driver_kw else
+                        "device_admission" if device_admission
+                        else "capacity_priced")
+                raise ValueError(f"driver options {what} only "
+                                 "apply with congestion_aware=True")
+            if preemption is not None and not device_admission:
+                raise ValueError("preemption= needs device_admission=True — "
+                                 "only the in-loop admission path reports the "
+                                 "shortfall preemption resolves")
+            if device_admission and "residual" in driver_kw:
+                raise ValueError("device_admission=True supplies the "
+                                 "orchestrator's residual ledger; don't also "
+                                 "pass residual= explicitly")
+            if (count is None) == (fleet is None):
+                raise ValueError("pass exactly one of count / fleet")
+            if fleet is not None:
+                if not congestion_aware:
+                    raise ValueError("fleet admission is congestion-coupled; "
+                                     "pass congestion_aware=True")
+                return self._begin_fleet_workloads(
+                    [int(c) for c in fleet], capacity_priced, driver_kw,
+                    device_admission=device_admission, preemption=preemption,
+                    priority=priority)
+            if capacity_priced:
+                if "capacity" in driver_kw:
+                    raise ValueError("capacity_priced=True supplies the "
+                                     "orchestrator's residual-capacity "
+                                     "snapshot; don't also pass capacity= "
+                                     "explicitly")
+                driver_kw = dict(driver_kw,
+                                 capacity=self._residual.astype(np.float64))
+            if count == 0:
+                return []
+            if device_admission:
+                return self._begin_device_admission(count, preemption,
+                                                    priority, driver_kw)
+            snapshot = self._avail()
+            driver_res = None
+            if congestion_aware:
+                planned, driver_res = plan_congestion(
+                    self.topo, self.cfg.k, count=count, avails=snapshot,
+                    **driver_kw)
+            else:
+                planned = plan_batch([self.topo] * count, self.cfg.k,
+                                     [snapshot] * count,
+                                     strategy=self.cfg.strategy)
+            progs: list[ReduceProgram] = []
+            admitted: list[np.ndarray] = []
+            collisions = 0
+            for blue, prog in planned:
+                if np.any(blue & (self._residual <= 0)):  # capacity collision
+                    blue, prog = plan(self.topo, self.cfg.k,
+                                      avail=self._avail(),
+                                      strategy=self.cfg.strategy)
+                    collisions += 1
+                self._residual[blue] -= 1
+                self.utilization_history.append(prog.utilization)
+                self._register_job(blue, prog, priority=priority)
+                progs.append(prog)
+                admitted.append(blue)
+            # each collision fallback is one extra host-side solve round trip
+            # on top of the wave's batched solve
+            self.last_admission = {
+                "path": "host", "solves": 1 + collisions,
+                "round_trips": 1 + collisions, "collisions": collisions,
+                "dropped": 0, "preempted": (), "cache_hit": False}
+            if driver_res is not None:
+                # collision fallbacks replace driver placements with
+                # utilization-only ones; re-measure so last_congestion reports
+                # what was actually admitted, not what the driver proposed
+                if collisions:
+                    from ..core.congestion import measure_fleet
+                    m = measure_fleet(
+                        self.topo.tree, [self.topo.load] * count, admitted,
+                        rho_weighted=driver_kw.get("rho_weighted", False))
+                    driver_res = dataclasses.replace(
+                        driver_res, blue=np.stack(admitted), costs=m.costs,
+                        msgs=m.msgs, congestion=m.congestion,
+                        max_congestion=m.max_congestion,
+                        mean_congestion=m.mean_congestion)
+                self.last_congestion = driver_res
+            return progs
 
     def _begin_device_admission(self, count: int,
                                 preemption: PreemptionPolicy | None,
@@ -1019,16 +1025,17 @@ class Orchestrator:
             self.preemption_events.append({
                 "policy": preemption.kind, "victims": tuple(evicted),
                 "freed": int(freed), "dropped_before": dropped})
-        progs: list[ReduceProgram] = []
-        for g, (blue, prog) in zip(tree_of, planned, strict=True):
-            self._residuals[g][blue] -= 1
-            self.utilization_history.append(prog.utilization)
-            self._register_job(blue, prog, tree=g, priority=priority)
-            progs.append(prog)
-        if any(np.any(r < 0) for r in self._residuals):
-            raise RuntimeError("in-loop fleet admission returned an "
-                               "infeasible placement — engine/ledger "
-                               "disagreement")
+        with telemetry.span("orchestrator.register"):
+            progs: list[ReduceProgram] = []
+            for g, (blue, prog) in zip(tree_of, planned, strict=True):
+                self._residuals[g][blue] -= 1
+                self.utilization_history.append(prog.utilization)
+                self._register_job(blue, prog, tree=g, priority=priority)
+                progs.append(prog)
+            if any(np.any(r < 0) for r in self._residuals):
+                raise RuntimeError("in-loop fleet admission returned an "
+                                   "infeasible placement — engine/ledger "
+                                   "disagreement")
         self.last_congestion = res
         self.last_admission = {
             "path": "device", "solves": solves, "round_trips": solves,
