@@ -19,17 +19,28 @@ gather in ``repro.engine`` consumes:
   * padded slots inside a block fold only identities and carry zero
     load / BIG rho, so their garbage stays finite and is never read.
 
-Everything here is host-side numpy. Per-tree structure (children matrix,
-depth buckets, rho-up table) is cached on the tree object's identity, so a
-fleet reusing one topology — the common serving pattern — pays the packing
-cost once. Batches of *similar* shapes share one compiled executable in
-the engine (the jit key is the packed layout + ``k``), so group instances
-by size when throughput matters.
+Everything here is host-side numpy, packed in two parts:
+
+  * the load-independent layout — every node-indexed structural array,
+    the level blocks, the slot maps, the packed children, parent pointers
+    and rho-up table — is cached per (tuple of tree identities,
+    ``bucket``) in a small LRU that drops an entry when one of its trees
+    dies (the per-tree children, depth buckets and rho-up table beneath it
+    are cached per tree likewise). Its arrays are read-only, and every
+    Forest built from one entry shares them: an in-place write raises;
+  * the loads and availability (``load``, ``avail``, ``send`` and their
+    packed twins) are packed per call through the cached slot maps.
+
+So a caller re-solving one tree list with fresh loads — the common serving
+pattern — pays for the layout once. Batches of *similar* shapes share one
+compiled executable in the engine (the jit key is the packed layout +
+``k``), so group instances by size when throughput matters.
 """
 from __future__ import annotations
 
 import dataclasses
 import weakref
+from collections import OrderedDict
 from typing import Sequence
 
 import numpy as np
@@ -178,31 +189,51 @@ def layout_stats() -> dict:
             "distinct_layouts": int(telemetry.get("engine.layouts"))}
 
 
-@telemetry.traced("engine.pack")
-def build_forest(
-    trees: Sequence[Tree],
-    loads: Sequence[np.ndarray],
-    avail: Sequence[np.ndarray] | None = None,
-    *,
-    bucket: bool = True,
-) -> Forest:
-    """Stack B (tree, load[, avail]) instances into one padded Forest.
+@dataclasses.dataclass(frozen=True)
+class _Layout:
+    """The load-independent part of a Forest, with the gather indices the
+    per-call pack reads; cached per tree tuple, every array read-only."""
 
-    ``bucket=True`` (default) rounds the layout dimensions that feed the
-    engine's jit key — per-level internal/leaf widths, ``max_children``,
-    the per-level subtree-size caps, and ``h_max`` (to the next even
-    height) — up to bucket boundaries (powers of two). Ragged multi-tenant
-    batches whose exact shapes differ then collapse onto a handful of
-    compiled executables instead of recompiling per layout; the extra slots
-    are ordinary padded slots (identity children, zero load) that the
-    sweep already tolerates. ``bucket=False`` packs exact shapes.
-    """
-    if len(trees) == 0:
-        raise ValueError("empty forest")
-    if len(loads) != len(trees):
-        raise ValueError(f"{len(loads)} loads for {len(trees)} trees")
-    if avail is not None and len(avail) != len(trees):
-        raise ValueError(f"{len(avail)} avail masks for {len(trees)} trees")
+    fields: dict                    # the Forest's structural fields
+    real: np.ndarray                # (B, S) bool: the slot holds a node
+    node_at: np.ndarray             # (B, S) flat index of each slot's node
+                                    #   into (B, n_max); 0 at padding
+    slot_at: np.ndarray             # (B, n_max) flat index of each node's
+                                    #   slot into (B, S+1); S at padding
+    sweep: tuple[tuple[int, int, np.ndarray], ...]
+    # bottom-up per level: (lvl_off, lvl_internal, (B, wi, max_c) flat
+    # index of each internal slot's children into (B, S+1)); the identity
+    # slot S reads 0
+
+
+# Load-independent layouts by (tree identities, bucket), least recently
+# used first. One BT(4096) x 64 entry holds about 70 MB; admission waves
+# bring a new tree tuple almost every wave, so the cache stays small.
+_LAYOUT_CACHE_SIZE = 4
+_LAYOUT_CACHE: OrderedDict[tuple, tuple] = OrderedDict()
+
+
+def _layout(trees: Sequence[Tree], bucket: bool) -> _Layout:
+    """The cached layout of ``trees``; counts ``engine.pack_hits``."""
+    key = (tuple(map(id, trees)), bool(bucket))
+    by_id = {id(t): t for t in trees}
+    hit = _LAYOUT_CACHE.get(key)
+    if hit is not None and all(hit[0][i]() is t for i, t in by_id.items()):
+        _LAYOUT_CACHE.move_to_end(key)
+        telemetry.count("engine.pack_hits")
+        return hit[1]
+    telemetry.count("engine.pack_hits", 0)
+    lay = _build_layout(trees, bucket)
+    refs = {i: weakref.ref(t, lambda _, k=key: _LAYOUT_CACHE.pop(k, None))
+            for i, t in by_id.items()}
+    _LAYOUT_CACHE[key] = (refs, lay)
+    _LAYOUT_CACHE.move_to_end(key)
+    while len(_LAYOUT_CACHE) > _LAYOUT_CACHE_SIZE:
+        _LAYOUT_CACHE.popitem(last=False)
+    return lay
+
+
+def _build_layout(trees: Sequence[Tree], bucket: bool) -> _Layout:
     B = len(trees)
     structs = [_tree_struct(t) for t in trees]
     n_max = max(t.n for t in trees)
@@ -216,8 +247,6 @@ def build_forest(
 
     parent = np.full((B, n_max), -2, np.int32)
     rho = np.ones((B, n_max), np.float64)
-    load_a = np.zeros((B, n_max), np.int64)
-    avail_a = np.zeros((B, n_max), bool)
     mask = np.zeros((B, n_max), bool)
     depth = np.full((B, n_max), -1, np.int32)
     root = np.zeros(B, np.int32)
@@ -229,14 +258,8 @@ def build_forest(
 
     for b, (t, s) in enumerate(zip(trees, structs)):
         n = t.n
-        L = np.asarray(loads[b], np.int64)
-        if L.shape != (n,):
-            raise ValueError(f"load {b} shape {L.shape} != ({n},)")
         parent[b, :n] = t.parent
         rho[b, :n] = t.rho
-        load_a[b, :n] = L
-        avail_a[b, :n] = (np.ones(n, bool) if avail is None or avail[b] is None
-                          else np.asarray(avail[b], bool))
         mask[b, :n] = True
         depth[b, :n] = t.depth
         root[b] = t.root
@@ -260,15 +283,6 @@ def build_forest(
             lvl[b, :ni] = s.internal[d]
             lvl[b, ni : ni + s.nl[d]] = s.leaf[d]
         levels.append(lvl)
-
-    # send(v) = 1 iff subtree load positive: bottom-up level sweep, batched
-    sub = load_a.copy()
-    for d in range(h_max, 0, -1):
-        nd = levels[d]
-        bv, wv = np.nonzero(nd < n_max)
-        vv = nd[bv, wv]
-        np.add.at(sub, (bv, parent[bv, vv]), sub[bv, vv])
-    send = (sub > 0).astype(np.int64)
 
     # ---- level-packed slot layout -----------------------------------------
     lvl_off, lvl_width, lvl_internal, lvl_sub = [], [], [], []
@@ -300,9 +314,6 @@ def build_forest(
     real = slot_node >= 0
     src = np.where(real, slot_node, 0)
     bix = np.arange(B)[:, None]
-    pk_load = np.where(real, load_a[bix, src], 0)
-    pk_send = np.where(real, send[bix, src], 0)
-    pk_avail = np.where(real, avail_a[bix, src], False)
     pk_rho_up = np.where(real[:, :, None], rho_up[bix, src], np.inf)
     ch = kid[bix, src]                                  # (B, S, max_c)
     ch_slot = np.where(
@@ -324,16 +335,100 @@ def build_forest(
     pk_par[bs, cs] = (ss - off_of_slot[ss]).astype(np.int32)
     pk_cidx[bs, cs] = ms.astype(np.int32)
 
-    f = Forest(trees=tuple(trees), parent=parent, rho=rho, load=load_a,
-               avail=avail_a, mask=mask, depth=depth, root=root, n=nn,
-               height=height, kid=kid, rho_up=rho_up, send=send,
-               sub_size=sub_size, levels=tuple(levels),
-               slot_of=slot_of, slot_node=slot_node, pk_kid=pk_kid,
-               pk_par=pk_par, pk_cidx=pk_cidx,
-               pk_load=pk_load, pk_send=pk_send, pk_avail=pk_avail,
-               pk_rho_up=pk_rho_up, lvl_off=tuple(lvl_off),
-               lvl_width=tuple(lvl_width),
-               lvl_internal=tuple(lvl_internal), lvl_sub=tuple(lvl_sub))
+    # flat gather indices for the per-call pack: slot space is (B, S+1),
+    # its last column the identity slot S
+    row = bix * (S + 1)
+    sweep = tuple(
+        (lvl_off[d], lvl_internal[d],
+         row[:, :, None] + pk_kid[:, lvl_off[d] : lvl_off[d]
+                                  + lvl_internal[d]])
+        for d in range(h_max, -1, -1) if lvl_internal[d])
+    lay = _Layout(
+        fields=dict(parent=parent, rho=rho, mask=mask, depth=depth,
+                    root=root, n=nn, height=height, kid=kid, rho_up=rho_up,
+                    sub_size=sub_size, levels=tuple(levels),
+                    slot_of=slot_of, slot_node=slot_node, pk_kid=pk_kid,
+                    pk_par=pk_par, pk_cidx=pk_cidx, pk_rho_up=pk_rho_up,
+                    lvl_off=tuple(lvl_off), lvl_width=tuple(lvl_width),
+                    lvl_internal=tuple(lvl_internal),
+                    lvl_sub=tuple(lvl_sub)),
+        real=real, node_at=bix * n_max + src, slot_at=row + slot_of,
+        sweep=sweep)
+    for a in (*lay.fields.values(), *levels, lay.real, lay.node_at,
+              lay.slot_at, *(idx for _, _, idx in sweep)):
+        if isinstance(a, np.ndarray):
+            a.flags.writeable = False
+    return lay
+
+
+def _pack_loads(lay: _Layout, trees: Sequence[Tree],
+                loads: Sequence[np.ndarray],
+                avail: Sequence[np.ndarray] | None) -> dict:
+    """The Forest's load-dependent fields over the cached layout ``lay``."""
+    B, n_max = lay.fields["mask"].shape
+    load_a = np.zeros((B, n_max), np.int64)
+    avail_a = (lay.fields["mask"].copy() if avail is None
+               else np.zeros((B, n_max), bool))
+    for b, t in enumerate(trees):
+        n = t.n
+        L = np.asarray(loads[b], np.int64)
+        if L.shape != (n,):
+            raise ValueError(f"load {b} shape {L.shape} != ({n},)")
+        load_a[b, :n] = L
+        if avail is not None:
+            avail_a[b, :n] = (True if avail[b] is None
+                              else np.asarray(avail[b], bool))
+    real = lay.real
+    S = real.shape[1]
+    pk_load = np.where(real, np.take(load_a, lay.node_at), 0)
+    # send(v) = 1 iff subtree load positive: bottom-up level sweep in slot
+    # space, each internal block adding its children's subtree loads
+    sub = np.zeros((B, S + 1), np.int64)
+    sub[:, :S] = pk_load
+    for off, wi, idx in lay.sweep:
+        sub[:, off : off + wi] += np.take(sub, idx).sum(axis=2)
+    pos = sub > 0
+    return dict(
+        load=load_a, avail=avail_a,
+        send=np.take(pos, lay.slot_at).astype(np.int64),
+        pk_load=pk_load, pk_send=pos[:, :S].astype(np.int64),
+        pk_avail=(real.copy() if avail is None
+                  else np.where(real, np.take(avail_a, lay.node_at), False)))
+
+
+@telemetry.traced("engine.pack")
+def build_forest(
+    trees: Sequence[Tree],
+    loads: Sequence[np.ndarray],
+    avail: Sequence[np.ndarray] | None = None,
+    *,
+    bucket: bool = True,
+) -> Forest:
+    """Stack B (tree, load[, avail]) instances into one padded Forest.
+
+    ``bucket=True`` (default) rounds the layout dimensions that feed the
+    engine's jit key — per-level internal/leaf widths, ``max_children``,
+    the per-level subtree-size caps, and ``h_max`` (to the next even
+    height) — up to bucket boundaries (powers of two). Ragged multi-tenant
+    batches whose exact shapes differ then collapse onto a handful of
+    compiled executables instead of recompiling per layout; the extra slots
+    are ordinary padded slots (identity children, zero load) that the
+    sweep already tolerates. ``bucket=False`` packs exact shapes.
+
+    The load-independent layout is cached per (tree identities,
+    ``bucket``): a second call with the same tree objects packs only the
+    loads and availability, and shares the read-only structural arrays of
+    the first.
+    """
+    if len(trees) == 0:
+        raise ValueError("empty forest")
+    if len(loads) != len(trees):
+        raise ValueError(f"{len(loads)} loads for {len(trees)} trees")
+    if avail is not None and len(avail) != len(trees):
+        raise ValueError(f"{len(avail)} avail masks for {len(trees)} trees")
+    lay = _layout(trees, bucket)
+    f = Forest(trees=tuple(trees), **lay.fields,
+               **_pack_loads(lay, trees, loads, avail))
     telemetry.count("engine.forests_built")
     telemetry.count_distinct("engine.layouts", layout_key(f))
     return f

@@ -372,11 +372,14 @@ def _device_inputs(f: Forest, dtype) -> tuple:
     the color sweep. Cached per (Forest identity, dtype): a serving loop
     re-solving one built Forest (the orchestrator replanning pattern)
     sanitizes and uploads the byte-identical arrays once, not per solve.
-    The cache assumes built Forests are immutable — mutating a Forest's
-    numpy arrays in place after a solve would silently reuse the stale
-    device copies; rebuild via :func:`build_forest` instead (cheap: the
-    per-tree structure is itself cached). Counts ``engine.upload_hits``,
-    and on a miss the device bytes written in ``engine.upload_bytes``.
+    The cache assumes built Forests are immutable. Their structural arrays
+    are read-only (shared through ``build_forest``'s layout cache); the
+    load-dependent ones (``pk_load``, ``pk_send``, ``pk_avail``) are not,
+    and mutating them in place after a solve would silently reuse the
+    stale device copies — rebuild via :func:`build_forest` instead (cheap
+    for the same trees: only the loads are packed again). Counts
+    ``engine.upload_hits``, and on a miss the device bytes written in
+    ``engine.upload_bytes``.
     """
     key = (id(f), np.dtype(dtype).str)
     hit = _INPUT_CACHE.get(key)
