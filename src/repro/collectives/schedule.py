@@ -482,20 +482,24 @@ def plan_fleet(fleet: Fleet, k: int,
 
     T tenants spread over the fleet's N aggregation trees, solved
     *jointly* by :func:`repro.engine.solve_fleet`: every penalty round
-    profiles the union of tree-local links and the fleet's shared-core
-    links, so tenants on different trees trade placements through the
-    links they share — two independent :func:`plan_congestion` calls
-    cannot see that coupling. Tenant assignment comes either from
-    ``counts`` (per-tree tenant counts; tenant loads default to each
-    tree's ``topo.load`` — the admission shape) or from explicit
-    ``loads`` + ``tree_of`` (one load vector per tenant, shaped for its
-    own tree). ``avails`` is an optional per-tenant mask list; each
-    tree's fault domains (``topo.blocked``) are subtracted for its own
-    tenants. ``capacity`` / ``residual`` in ``driver_kw`` are per-*tree*
-    lists of capacity vectors / hard-admission ledgers, validated here at
-    the call boundary. Compiles one
-    :class:`ReduceProgram` per tenant on its own tree and returns a
-    :class:`FleetPlan`.
+    profiles the union of the fleet's physical tree links and its
+    shared-core links, so tenants on different trees trade placements
+    through the links they share — two independent :func:`plan_congestion`
+    calls cannot see that coupling. Tenant assignment comes either from
+    ``counts`` (tenants per candidate group of ``fleet.groups``; each
+    tenant aggregates on a tree of its group that the loop chooses, and
+    its load is that group's ``topo.load`` — the admission shape) or from
+    explicit ``loads`` + ``tree_of`` (one load vector per tenant, shaped
+    for its own tree, which is fixed). ``avails`` is an optional
+    per-tenant mask list; each tree's fault domains (``topo.blocked``)
+    are subtracted for its own tenants. ``capacity`` / ``residual`` in
+    ``driver_kw`` are capacity vectors / hard-admission ledgers: one
+    vector per physical switch (``fleet.n_switches``), or, for a fleet
+    whose trees share no switch, one per tree; validated here at the
+    call boundary. ``residual_after`` on the result is per physical
+    switch. Compiles one :class:`ReduceProgram` per tenant on the tree it
+    was given and returns a :class:`FleetPlan` whose ``tree_of`` names
+    those trees.
 
     For an N=1 fleet with no core links this is exactly
     :func:`plan_congestion` on the single topology — same masks, same
@@ -506,6 +510,7 @@ def plan_fleet(fleet: Fleet, k: int,
                         "with Fleet.single(topo)")
     with telemetry.span("engine.prepare"):
         N = fleet.n_trees
+        cand = None
         if (loads is None) == (counts is None):
             raise ValueError("pass exactly one of loads / counts")
         if counts is not None:
@@ -513,11 +518,21 @@ def plan_fleet(fleet: Fleet, k: int,
                 raise ValueError("tree_of is derived from counts — pass it "
                                  "only with explicit loads")
             counts = [int(c) for c in counts]
-            if len(counts) != N or any(c < 1 for c in counts):
+            G = fleet.n_groups
+            if len(counts) != G or any(c < 1 for c in counts):
                 raise ValueError(f"counts must give >=1 tenants for each of "
-                                 f"the {N} trees, got {counts}")
-            tree_of = [g for g, c in enumerate(counts) for _ in range(c)]
+                                 f"the {G} trees, got {counts}")
+            group_of = [i for i, c in enumerate(counts) for _ in range(c)]
+            tree_of = [fleet.groups[i][0] for i in group_of]
             loads = [fleet.topos[g].load for g in tree_of]
+            P = max(len(fleet.groups[i]) for i in set(group_of))
+            if P > 1:
+                # a short group repeats its first tree: a duplicate row
+                # ties with it and never changes the answer
+                cand = np.asarray([
+                    list(fleet.groups[i])
+                    + [fleet.groups[i][0]] * (P - len(fleet.groups[i]))
+                    for i in group_of], np.int32)
         else:
             if tree_of is None:
                 raise ValueError("explicit loads need tree_of (one tree index "
@@ -542,36 +557,57 @@ def plan_fleet(fleet: Fleet, k: int,
         # per-tree fault domains + mask validation at the boundary
         avails = [fleet.topos[g].candidates(av)
                   for g, av in zip(tree_of, avails)]
-        if driver_kw.get("capacity") is not None:
-            caps = list(driver_kw["capacity"])
-            if len(caps) != N:
-                raise ValueError(f"{len(caps)} capacity vectors for {N} trees "
-                                 "— plan_fleet takes one per tree")
-            driver_kw["capacity"] = [
-                _check_capacity(c, fleet.topos[g].tree.n, "plan_fleet")
-                * (np.clip(fleet.topos[g].cap_scale, 0.0, 1.0)
-                   if fleet.topos[g].cap_scale is not None else 1.0)
-                for g, c in enumerate(caps)]
-        if driver_kw.get("residual") is not None:
-            resid = list(driver_kw["residual"])
-            if len(resid) != N:
-                raise ValueError(f"{len(resid)} residual ledgers for {N} "
-                                 "trees — plan_fleet takes one per tree")
-            driver_kw["residual"] = [
-                _check_residual(rg, fleet.topos[g].tree.n, "plan_fleet")
-                for g, rg in enumerate(resid)]
+        for key in ("capacity", "residual"):
+            if driver_kw.get(key) is not None:
+                driver_kw[key] = _fleet_ledger(fleet, driver_kw[key], key)
     from ..engine import solve_fleet
     res = solve_fleet([tp.tree for tp in fleet.topos], loads, tid, k,
                       avails,
                       core_rho=fleet.core_rho if fleet.n_core else None,
                       core_path=fleet.core_path if fleet.n_core else None,
-                      **driver_kw)
+                      candidates=cand, switch_id=fleet.switch_id,
+                      link_id=fleet.link_id, **driver_kw)
     with telemetry.span("schedule.build_programs"):
         plans = []
-        for t, (L, g) in enumerate(zip(loads, tree_of, strict=True)):
+        for L, g in zip(loads, res.tree_of, strict=True):
             tp = fleet.topos[g]
-            blue = res.blue[t, : tp.tree.n]
+            blue = res.blue[len(plans), : tp.tree.n]
             tenant_topo = dataclasses.replace(tp, load=np.asarray(L, np.int64))
             prog = build_program(tenant_topo, blue)
             plans.append(TenantPlan(blue, prog, prog.utilization))
-    return FleetPlan(plans, res, tid)
+    return FleetPlan(plans, res, res.tree_of)
+
+
+def _fleet_ledger(fleet: Fleet, vec, what: str) -> np.ndarray:
+    """A capacity vector or residual ledger per physical switch: given so,
+    or gathered from one vector per tree of a fleet that shares no
+    switch. Capacity is scaled by each tree's degraded fraction."""
+    W = fleet.n_switches
+    if isinstance(vec, np.ndarray) and vec.ndim == 1:
+        if what == "residual":
+            return _check_residual(vec, W, "plan_fleet")
+        out = _check_capacity(vec, W, "plan_fleet")
+        for g, tp in enumerate(fleet.topos):
+            if tp.cap_scale is not None:
+                out[fleet.switch_id[g]] *= np.clip(tp.cap_scale, 0.0, 1.0)
+        return out
+    vecs = list(vec)
+    N = fleet.n_trees
+    if len(vecs) != N:
+        noun = "ledgers" if what == "residual" else "vectors"
+        raise ValueError(f"{len(vecs)} {what} {noun} for {N} trees "
+                         "— plan_fleet takes one per tree")
+    if fleet.shared:
+        raise ValueError(f"plan_fleet: this fleet's trees share switches; "
+                         f"give {what} per physical switch ({W},)")
+    out = np.zeros(W, np.int64 if what == "residual" else np.float64)
+    for g, (x, tp) in enumerate(zip(vecs, fleet.topos)):
+        n = tp.tree.n
+        if what == "residual":
+            x = _check_residual(x, n, "plan_fleet")
+        else:
+            x = _check_capacity(x, n, "plan_fleet") * (
+                np.clip(tp.cap_scale, 0.0, 1.0)
+                if tp.cap_scale is not None else 1.0)
+        out[fleet.switch_id[g]] = x
+    return out

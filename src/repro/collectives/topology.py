@@ -10,6 +10,7 @@ budget k models how many rack/pod reduction points a tenant may claim
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -269,11 +270,27 @@ class Fleet:
     the shared core), which is how tenants on *different* trees become
     congestion-coupled: they meet on shared core link ids.
 
+    **Physical ids.** Node v of tree g is the physical switch
+    ``switch_id[g][v]``, and its up-link the physical link ``link_id[g][v]``.
+    By default every tree owns its switches and links: tree g's ids are
+    the segment ``[off_g, off_g + n_g)``, ``off_g = sum n_<g``. Trees of a
+    multi-rooted fabric share switches and links (the candidate trees of
+    one pod share its edge switches and their up-links): give them the
+    same ids. Ids are relabelled in order of first appearance (tree by
+    tree, node by node), so tree 0's switches are always ``0 .. n_0 - 1``
+    and a fleet without sharing keeps the segment layout. Capacity is
+    claimed per physical switch and congestion summed per physical link.
+
+    **Candidate groups.** A tenant belongs to a group and aggregates on
+    any tree of ``groups[i]``, the loop's own choice (see
+    :func:`repro.engine.solve_fleet`). Trees of a group are node-aligned:
+    the same node count, and node v has the same role and load in each.
+    By default every tree is its own group: one path per tenant.
+
     Link ids live in one **global link-id space** so per-link traffic from
     different trees lands in one congestion profile::
 
-        [0, n_0)                      tree 0's switch up-links
-        [off_g, off_g + n_g)          tree g's up-links, off_g = sum n_<g
+        [0, core_offset)              the trees' physical up-links
         [core_offset, core_offset+C)  the shared-core links
 
     The single-tree case is the degenerate ``N=1, C=0`` fleet
@@ -283,6 +300,9 @@ class Fleet:
     topos: tuple[ClusterTopology, ...]
     core_rho: np.ndarray                    # (C,) reciprocal rates; C may be 0
     core_path: tuple[tuple[int, ...], ...]  # per tree: core link ids crossed
+    switch_id: tuple[np.ndarray, ...] | None = None  # per tree: (n_g,)
+    link_id: tuple[np.ndarray, ...] | None = None    # per tree: (n_g,)
+    groups: tuple[tuple[int, ...], ...] | None = None  # candidate trees
 
     def __post_init__(self):
         if not self.topos:
@@ -308,6 +328,60 @@ class Fleet:
                 if not 0 <= c < C:
                     raise ValueError(f"core link {c} on tree {g}'s path out "
                                      f"of range [0, {C})")
+        object.__setattr__(self, "switch_id",
+                           self._physical(self.switch_id, "switch"))
+        object.__setattr__(self, "link_id",
+                           self._physical(self.link_id, "link"))
+        N = len(self.topos)
+        groups = (tuple((g,) for g in range(N)) if self.groups is None
+                  else tuple(tuple(int(g) for g in grp)
+                             for grp in self.groups))
+        object.__setattr__(self, "groups", groups)
+        for i, grp in enumerate(groups):
+            if not grp:
+                raise ValueError(f"candidate group {i} is empty")
+            if len(set(grp)) != len(grp):
+                raise ValueError(f"candidate group {i} repeats a tree: {grp}")
+            for g in grp:
+                if not 0 <= g < N:
+                    raise ValueError(f"tree {g} of candidate group {i} out "
+                                     f"of range [0, {N})")
+            n0 = self.topos[grp[0]].tree.n
+            if any(self.topos[g].tree.n != n0 for g in grp):
+                raise ValueError(f"candidate group {i} mixes tree sizes; "
+                                 "its trees must be node-aligned")
+
+    def _physical(self, ids, what: str) -> tuple[np.ndarray, ...]:
+        """Per-tree physical ids, validated and relabelled densely in
+        order of first appearance; the segment layout by default."""
+        ns = [tp.tree.n for tp in self.topos]
+        if ids is None:
+            offs = np.concatenate([[0], np.cumsum(ns)[:-1]]).astype(np.int64)
+            out = tuple(np.arange(o, o + n, dtype=np.int64)
+                        for o, n in zip(offs, ns))
+        else:
+            if len(ids) != len(ns):
+                raise ValueError(f"{len(ids)} {what} id maps for "
+                                 f"{len(ns)} trees")
+            raw = [np.asarray(x) for x in ids]
+            for g, (x, n) in enumerate(zip(raw, ns)):
+                if x.shape != (n,) or not np.issubdtype(x.dtype, np.integer):
+                    raise ValueError(f"{what} ids of tree {g} must be {n} "
+                                     f"integers, got {x.dtype} {x.shape}")
+                if len(np.unique(x)) != n:
+                    raise ValueError(f"tree {g} names one physical {what} "
+                                     "twice")
+            flat = np.concatenate(raw)
+            _, first, inv = np.unique(flat, return_index=True,
+                                      return_inverse=True)
+            rank = np.empty(first.size, np.int64)
+            rank[np.argsort(first, kind="stable")] = np.arange(first.size)
+            dense = rank[inv]
+            cuts = np.cumsum(ns)[:-1]
+            out = tuple(np.split(dense, cuts))
+        for x in out:
+            x.flags.writeable = False
+        return out
 
     @property
     def n_trees(self) -> int:
@@ -318,28 +392,118 @@ class Fleet:
         return int(self.core_rho.size)
 
     @property
+    def n_groups(self) -> int:
+        return len(self.groups)
+
+    @functools.cached_property
+    def n_switches(self) -> int:
+        """Physical switches of the fabric (the capacity ledger's size)."""
+        return 1 + max(int(x.max()) for x in self.switch_id)
+
+    @property
     def link_offsets(self) -> tuple[int, ...]:
-        """Global-link-id segment start of each tree's up-links."""
+        """Global-link-id segment start of each tree's up-links (the
+        default layout, where every tree owns its links)."""
         offs, s = [], 0
         for tp in self.topos:
             offs.append(s)
             s += tp.tree.n
         return tuple(offs)
 
-    @property
+    @functools.cached_property
     def core_offset(self) -> int:
         """First global link id of the shared-core segment."""
-        return sum(tp.tree.n for tp in self.topos)
+        return 1 + max(int(x.max()) for x in self.link_id)
 
     @property
     def n_links(self) -> int:
         return self.core_offset + self.n_core
+
+    @property
+    def shared(self) -> bool:
+        """Whether any switch or link belongs to more than one tree."""
+        n = sum(tp.tree.n for tp in self.topos)
+        return self.n_switches < n or self.core_offset < n
 
     @classmethod
     def single(cls, topo: ClusterTopology) -> "Fleet":
         """The degenerate one-tree fleet (no shared core)."""
         return cls(topos=(topo,), core_rho=np.zeros(0, np.float64),
                    core_path=((),))
+
+
+def fat_tree_fleet(k: int = 16, *, hosts_per_edge: int | None = None,
+                   pods: int | None = None, rho: float = 1.0,
+                   paths: str = "all") -> Fleet:
+    """The aggregation trees of a k-ary fat-tree (Al-Fares et al.,
+    SIGCOMM 2008, Sec. 3) toward one destination host.
+
+    Each of ``pods`` worker pods (default k) has k/2 edge switches, each
+    with ``hosts_per_edge`` hosts (default k/2) sending one message, and
+    k/2 aggregation switches; the (k/2)^2 core switches form k/2 groups of
+    k/2, core group a reaching aggregation switch a of every pod. A pod's
+    tree is one (aggregation switch a, core switch c of group a) path:
+    its k/2 edge switches, then a, then c (nodes ``[c, a, e_0, ..]``);
+    from c the messages cross the destination pod's aggregation switch a
+    to the destination's edge switch (core link a) and that edge switch's
+    access link (core link k/2). Every link has reciprocal rate ``rho``.
+
+    ``paths="all"`` gives every pod its (k/2)^2 trees as one candidate
+    group, candidate i taking aggregation switch ``i mod k/2`` and core
+    switch ``i // (k/2)`` of that group, so consecutive candidates vary
+    the aggregation switch first. The trees share physical ids: a pod's
+    edge switches and their up-links to an aggregation switch, the
+    aggregation switches, and the core switches and their down-links,
+    across pods. ``paths="pinned"`` gives pod p the one tree through
+    aggregation switch ``p mod k/2`` and core switch ``p // (k/2)`` of
+    that group: one path per pod, no switch shared between pods' trees.
+    All trees share one :class:`~repro.core.tree.Tree` object, so the
+    engine packs any batch of them through one cached layout.
+    """
+    if k < 2 or k % 2:
+        raise ValueError(f"fat-tree arity k must be even and >= 2, got {k}")
+    h = k // 2
+    hosts = h if hosts_per_edge is None else int(hosts_per_edge)
+    P = k if pods is None else int(pods)
+    if hosts < 1 or P < 1:
+        raise ValueError("need at least one pod and one host per edge")
+    if not (np.isfinite(rho) and rho > 0):
+        raise ValueError(f"link rate rho must be positive, got {rho}")
+    if paths not in ("all", "pinned"):
+        raise ValueError(f"paths must be 'all' or 'pinned', got {paths!r}")
+    n = 2 + h
+    tree = Tree(np.asarray([DEST, 0] + [1] * h, np.int32), np.full(n, rho))
+    load = np.asarray([0, 0] + [hosts] * h, np.int64)
+    topo = ClusterTopology(tree=tree,
+                           device_leaf=np.repeat(np.arange(n), load),
+                           load=load)
+    if paths == "all":
+        per_pod = [[(i % h, i // h) for i in range(h * h)] for _ in range(P)]
+    else:
+        per_pod = [[(p % h, (p // h) % h)] for p in range(P)]
+    # physical ids: edge (p, e), aggregation (p, a), core (a, c) switches;
+    # up-links edge (p, e) -> agg (p, a), agg (p, a) -> core (a, c), and
+    # core (a, c) -> the destination pod
+    n_edge, n_agg = P * h, P * h
+    sw_ids, ln_ids, core_path, groups = [], [], [], []
+    for p, cands in enumerate(per_pod):
+        grp = []
+        for a, c in cands:
+            core = n_edge + n_agg + a * h + c
+            agg = n_edge + p * h + a
+            edges = p * h + np.arange(h)
+            sw_ids.append(np.asarray([core, agg, *edges], np.int64))
+            up_core = a * h + c
+            up_agg = h * h + (p * h + a) * h + c
+            up_edge = h * h + P * h * h + (p * h + np.arange(h)) * h + a
+            ln_ids.append(np.asarray([up_core, up_agg, *up_edge], np.int64))
+            core_path.append((a, h))
+            grp.append(len(sw_ids) - 1)
+        groups.append(tuple(grp))
+    N = len(sw_ids)
+    return Fleet(topos=(topo,) * N, core_rho=np.full(h + 1, rho),
+                 core_path=tuple(core_path), switch_id=tuple(sw_ids),
+                 link_id=tuple(ln_ids), groups=tuple(groups))
 
 
 def build_fleet(n_trees: int = 2, n_pods: int = 2, racks_per_pod: int = 4,

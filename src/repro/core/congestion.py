@@ -245,8 +245,9 @@ class MultiFleetMeasurement(NamedTuple):
 
 
 def measure_fleet_multi(trees, tree_of, loads, blues, core_rho=None,
-                        core_path=None,
-                        rho_weighted: bool = False) -> MultiFleetMeasurement:
+                        core_path=None, rho_weighted: bool = False,
+                        link_id=None,
+                        n_links: int | None = None) -> MultiFleetMeasurement:
     """Host-side measurement for a multi-tree fleet sharing a core.
 
     ``trees``: the N distinct trees; ``tree_of[t]`` names tenant t's tree;
@@ -257,6 +258,12 @@ def measure_fleet_multi(trees, tree_of, loads, blues, core_rho=None,
     core link is the sum of those root counts over the tenants crossing
     it (times ``core_rho`` when ``rho_weighted``). For ``N=1, C=0`` this
     reduces exactly to :func:`measure_fleet` — same sums, same casts.
+
+    ``link_id[g]`` (optional, (n_g,) ints) names the physical link of each
+    node's up-link when trees share links (a multi-rooted fabric): the
+    tree part of ``congestion`` is then one entry per physical link, each
+    summing every tenant that crosses it, whichever tree it came through;
+    ``n_links`` (optional) is the count of physical links.
     """
     trees = list(trees)
     tid = np.asarray(list(tree_of), np.int64)
@@ -264,8 +271,10 @@ def measure_fleet_multi(trees, tree_of, loads, blues, core_rho=None,
     crho = (np.zeros(0, np.float64) if core_rho is None
             else np.asarray(core_rho, np.float64))
     C = crho.size
-    path = (tuple(() for _ in trees) if core_path is None
-            else tuple(tuple(int(c) for c in p) for p in core_path))
+    crosses = np.zeros((T, C), bool)         # tenant t crosses core link c
+    if core_path is not None:
+        for t in range(T):
+            crosses[t, [int(c) for c in core_path[int(tid[t])]]] = True
     tree_n = np.asarray([t.n for t in trees], np.int64)
     link_off = np.concatenate([[0], np.cumsum(tree_n)[:-1]]).astype(np.int64)
     n_big = int(tree_n.max())
@@ -278,16 +287,26 @@ def measure_fleet_multi(trees, tree_of, loads, blues, core_rho=None,
         msgs[t, : tr.n] = m
         costs[t] = (m * tr.rho).sum()
     segs = []
-    for g, tr in enumerate(trees):
-        rows = msgs[tid == g][:, : tr.n]
-        prof_g = congestion_profile(rows, tr.rho if rho_weighted else None)
-        segs.append(prof_g)
+    if link_id is not None:
+        if n_links is None:
+            n_links = 1 + max(int(np.max(x)) for x in link_id)
+        seg = np.zeros(n_links, np.float64 if rho_weighted else np.int64)
+        for t in range(T):
+            tr = trees[int(tid[t])]
+            m = msgs[t, : tr.n]
+            np.add.at(seg, link_id[int(tid[t])],
+                      m * tr.rho if rho_weighted else m)
+        segs.append(seg)
+    else:
+        for g, tr in enumerate(trees):
+            rows = msgs[tid == g][:, : tr.n]
+            segs.append(congestion_profile(
+                rows, tr.rho if rho_weighted else None))
     root_msgs = np.asarray(
         [msgs[t, trees[int(tid[t])].root] for t in range(T)], np.int64)
     core = np.zeros(C, np.float64 if rho_weighted else np.int64)
     for c in range(C):
-        crossing = np.asarray([c in path[int(tid[t])] for t in range(T)])
-        cnt = root_msgs[crossing].sum()
+        cnt = root_msgs[crosses[:, c]].sum()
         core[c] = cnt * crho[c] if rho_weighted else cnt
     prof = np.concatenate(segs + [core]) if C else np.concatenate(segs)
     carrying = prof[prof > 0]

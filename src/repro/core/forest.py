@@ -39,6 +39,7 @@ compiled executable in the engine (the jit key is the packed layout +
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import weakref
 from collections import OrderedDict
 from typing import Sequence
@@ -369,7 +370,18 @@ def _pack_loads(lay: _Layout, trees: Sequence[Tree],
     load_a = np.zeros((B, n_max), np.int64)
     avail_a = (lay.fields["mask"].copy() if avail is None
                else np.zeros((B, n_max), bool))
-    for b, t in enumerate(trees):
+    ns = lay.fields["n"]
+    stacked = (isinstance(loads, np.ndarray) and ns.min() == ns.max()
+               and (avail is None or isinstance(avail, np.ndarray)))
+    if stacked:
+        # one (B, n) array for rows of one size: pack it whole
+        n = int(ns[0])
+        if loads.shape != (B, n):
+            raise ValueError(f"loads shape {loads.shape} != ({B}, {n})")
+        load_a[:, :n] = loads
+        if avail is not None:
+            avail_a[:, :n] = np.broadcast_to(np.asarray(avail, bool), (B, n))
+    for b, t in enumerate(() if stacked else trees):
         n = t.n
         L = np.asarray(loads[b], np.int64)
         if L.shape != (n,):
@@ -436,36 +448,40 @@ def build_forest(
 
 @dataclasses.dataclass(frozen=True)
 class FleetLayout:
-    """Per-tree segment + shared-link index maps for a multi-tree forest.
+    """Per-row tree, candidate and shared-link index maps for a fleet forest.
 
-    ``build_fleet_forest`` packs T tenant instances — tenant t living on
-    tree ``tree_of[t]`` — through the ordinary :func:`build_forest` path
-    (a single-tree fleet therefore produces a bit-identical ``Forest`` to
-    today's ``build_forest``), and this side table records how the
-    instances map back onto the fleet's **global link-id space**: tree g's
-    switch up-links occupy ``[link_off[g], link_off[g] + tree_n[g])`` and
-    the C shared-core links occupy ``[core_offset, core_offset + C)``.
+    ``build_fleet_forest`` packs one batch row per (tenant, candidate
+    tree) pair — row ``t * P + c`` is tenant t on tree ``cand[t, c]`` —
+    through the ordinary :func:`build_forest` path (a single-tree fleet
+    therefore produces a bit-identical ``Forest`` to today's
+    ``build_forest``), and this side table records how the rows map back
+    onto the fleet: each tenant's home tree, its candidates, and the
+    shared-core links each row crosses. With one candidate per tenant
+    (``P == 1``) rows are tenants.
     """
 
-    tree_of: np.ndarray            # (T,) int32 tenant -> tree index
+    tree_of: np.ndarray            # (T,) int32 tenant -> home tree
     n_trees: int
-    rep: np.ndarray                # (N,) int64 first tenant on each tree —
-                                   #   that batch row carries the tree's
-                                   #   canonical layout (slot_of etc.)
     tree_n: np.ndarray             # (N,) int64 real node count per tree
-    link_off: np.ndarray           # (N,) int64 global-link segment starts
-    core_offset: int               # first global id of the core segment
     core_rho: np.ndarray           # (C,) float64; C may be 0
     core_path: tuple[tuple[int, ...], ...]  # per tree: core links crossed
-    core_inc: np.ndarray           # (T, C) bool — tenant t crosses core c
+    core_inc: np.ndarray           # (R, C) bool — row r crosses core c
+    cand: np.ndarray | None = None  # (T, P) int32 candidate trees; None =
+                                    #   tree_of, one candidate each
 
     @property
     def n_core(self) -> int:
         return int(self.core_rho.size)
 
     @property
-    def n_links(self) -> int:
-        return self.core_offset + self.n_core
+    def paths(self) -> int:
+        """Candidates per tenant (P)."""
+        return 1 if self.cand is None else int(self.cand.shape[1])
+
+    @property
+    def row_tree(self) -> np.ndarray:
+        """(R,) tree of each batch row."""
+        return self.tree_of if self.cand is None else self.cand.reshape(-1)
 
 
 @telemetry.traced("engine.pack")
@@ -477,17 +493,22 @@ def build_fleet_forest(
     *,
     core_rho: np.ndarray | None = None,
     core_path: Sequence[Sequence[int]] | None = None,
+    candidates: np.ndarray | None = None,
     bucket: bool = True,
 ) -> tuple[Forest, FleetLayout]:
     """Pack T tenants living on N distinct trees into one Forest + layout.
 
-    ``trees`` holds the N *distinct* tree objects; ``tree_of[t]`` names
-    tenant t's tree. The Forest itself is built by replicating each
-    tenant's tree into the batch — exactly ``build_forest([trees[g] for g
-    in tree_of], ...)`` — so for ``tree_of == [0]*T`` the packed layout is
-    bit-identical to the single-tree call it refactors. Every tree must
-    carry at least one tenant (the per-tree congestion profile needs a
-    representative batch row for its layout).
+    ``trees`` holds the N trees; ``tree_of[t]`` names tenant t's home
+    tree. The Forest itself is built by replicating each tenant's tree
+    into the batch — exactly ``build_forest([trees[g] for g in tree_of],
+    ...)`` — so for ``tree_of == [0]*T`` the packed layout is
+    bit-identical to the single-tree call it refactors.
+
+    ``candidates`` (T, P) lists every tenant's candidate trees, node-aligned
+    with its home tree: the batch then holds T * P rows, row ``t * P + c``
+    tenant t's load on tree ``candidates[t, c]``, and ``avail`` holds one
+    mask per row. Candidate trees that share one :class:`Tree` object pack
+    through one cached layout.
     """
     N = len(trees)
     if N == 0:
@@ -500,36 +521,47 @@ def build_fleet_forest(
         raise ValueError(f"{len(loads)} loads for {T} tenants")
     if tid.min() < 0 or tid.max() >= N:
         raise ValueError(f"tree_of entries must lie in [0, {N})")
-    rep = np.full(N, -1, np.int64)
-    for t in range(T - 1, -1, -1):
-        rep[tid[t]] = t
-    if (rep < 0).any():
-        empty = [int(g) for g in np.nonzero(rep < 0)[0]]
-        raise ValueError(f"trees {empty} carry no tenant — every fleet "
-                         f"tree needs at least one")
     tree_n = np.asarray([t.n for t in trees], np.int64)
-    link_off = np.concatenate([[0], np.cumsum(tree_n)[:-1]])
-    core_offset = int(tree_n.sum())
+    cand = None
+    rows_tree, rows_load = tid, list(loads)
+    if candidates is not None:
+        cand = np.asarray(candidates, np.int32)
+        if cand.ndim != 2 or cand.shape[0] != T or cand.shape[1] < 1:
+            raise ValueError(f"candidates shape {cand.shape} is not "
+                             f"({T}, P)")
+        if cand.min() < 0 or cand.max() >= N:
+            raise ValueError(f"candidate trees must lie in [0, {N})")
+        off = np.nonzero((tree_n[cand] != tree_n[tid][:, None]).any(1))[0]
+        if off.size:
+            raise ValueError(f"tenant {int(off[0])}'s candidate trees are "
+                             "not node-aligned with its home tree")
+        rows_tree = cand.reshape(-1)
+        P = cand.shape[1]
+        rows_load = np.repeat(
+            np.stack([np.asarray(L, np.int64) for L in loads]), P, axis=0)
     crho = (np.zeros(0, np.float64) if core_rho is None
             else np.asarray(core_rho, np.float64))
     C = crho.size
     if crho.ndim != 1:
         raise ValueError(f"core_rho must be 1-D, got shape {crho.shape}")
-    path = (tuple(() for _ in range(N)) if core_path is None
-            else tuple(tuple(int(c) for c in p) for p in core_path))
+    path = (((),) * N if core_path is None
+            else tuple(tuple(map(int, p)) for p in core_path))
     if len(path) != N:
         raise ValueError(f"{len(path)} core paths for {N} trees")
-    core_inc = np.zeros((T, C), bool)
-    for g, p in enumerate(path):
-        for c in p:
-            if not 0 <= c < C:
-                raise ValueError(f"core link {c} on tree {g}'s path out of "
-                                 f"range [0, {C})")
-        core_inc[tid == g] = np.isin(np.arange(C), list(p))
-    f = build_forest([trees[g] for g in tid], list(loads), avail,
+    lens = np.fromiter(map(len, path), np.int64, N)
+    flat = np.fromiter(itertools.chain.from_iterable(path), np.int64,
+                       int(lens.sum()))
+    owner = np.repeat(np.arange(N), lens)
+    bad = np.nonzero((flat < 0) | (flat >= C))[0]
+    if bad.size:
+        raise ValueError(f"core link {int(flat[bad[0]])} on tree "
+                         f"{int(owner[bad[0]])}'s path out of range "
+                         f"[0, {C})")
+    inc = np.zeros((N, C), bool)
+    inc[owner, flat] = True
+    core_inc = inc[rows_tree]
+    f = build_forest([trees[g] for g in rows_tree], rows_load, avail,
                      bucket=bucket)
-    lay = FleetLayout(tree_of=tid, n_trees=N, rep=rep, tree_n=tree_n,
-                      link_off=link_off.astype(np.int64),
-                      core_offset=core_offset, core_rho=crho,
-                      core_path=path, core_inc=core_inc)
+    lay = FleetLayout(tree_of=tid, n_trees=N, tree_n=tree_n, core_rho=crho,
+                      core_path=path, core_inc=core_inc, cand=cand)
     return f, lay

@@ -437,32 +437,24 @@ class ChaosHarness:
                      f"claim ledger imbalance: {handed_out} capacity "
                      f"claimed vs {int(o.blue.sum())} blue + "
                      f"{self._extra_claims} admitted")
-            # per-switch conservation: each tree's residual plus the job
-            # registry's claims against it (and the orchestrator's own
-            # blue on tree 0) must reconstruct the effective capacity of
-            # every switch exactly — no claim leaks, no double-frees
-            eff0 = np.asarray([o._effective_capacity(sc)
-                               for sc in o._switch_scale], np.int64)
-            for g, res_g in enumerate(o._residuals):
-                if res_g is None:
-                    continue
-                total = res_g.astype(np.int64, copy=True)
-                for j in o.jobs.values():
-                    if j.tree == g:
-                        total += j.blue.astype(np.int64)
-                if g == 0:
-                    total += o.blue.astype(np.int64)
-                    eff = eff0
-                else:
-                    eff = np.full(res_g.shape[0], o.cfg.capacity,
-                                  np.int64)
-                if not np.array_equal(total, eff):
-                    s = int(np.nonzero(total != eff)[0][0])
-                    _require(False,
-                             f"per-switch claim conservation broken on "
-                             f"tree {g} switch {s}: residual+claims "
-                             f"{int(total[s])} != effective capacity "
-                             f"{int(eff[s])}")
+            # per-switch conservation: the physical ledger plus the job
+            # registry's claims on it (through each job's tree) and the
+            # orchestrator's own blue on tree 0 must reconstruct the
+            # effective capacity of every switch exactly — no claim
+            # leaks, no double-frees
+            eff = np.full(o.fabric_ledger.size, o.cfg.capacity, np.int64)
+            eff[: o._residual.size] = [o._effective_capacity(sc)
+                                       for sc in o._switch_scale]
+            total = o.fabric_ledger.astype(np.int64, copy=True)
+            for j in o.jobs.values():
+                np.add.at(total, o.fleet.switch_id[j.tree][j.blue], 1)
+            total[: o.blue.size] += o.blue
+            if not np.array_equal(total, eff):
+                s = int(np.nonzero(total != eff)[0][0])
+                _require(False,
+                         f"per-switch claim conservation broken on "
+                         f"switch {s}: residual+claims {int(total[s])} != "
+                         f"effective capacity {int(eff[s])}")
         fresh_util = phi_degraded(o.topo.tree, o.topo.load, o.blue,
                                   o.topo.cap_scale)
         _require(o.program.utilization == fresh_util,

@@ -77,7 +77,8 @@ class JobRecord:
     """
 
     job_id: int
-    tree: int                 # fleet tree the claims live on
+    tree: int                 # fleet tree the job was given; its claims
+                              # are on that tree's physical switches
     blue: np.ndarray          # (n,) bool claim mask (mutated by evictions)
     priority: int             # higher = evicted later
     order: int                # admission sequence number (age)
@@ -149,14 +150,10 @@ class Orchestrator:
         self._link_rate = np.ones(n)              # up-link rate fraction
         self._switch_scale = np.ones(n)           # aggregation-capacity
                                                   # fraction vs pristine
-        # residual aggregation capacity (None = unbounded); one ledger per
-        # fleet tree — index 0 IS self._residual (same array object)
-        self._residual = (np.full(n, cfg.capacity, np.int64)
-                          if cfg.capacity is not None else None)
-        self._residuals = [self._residual] + [
-            np.full(tp.tree.n, cfg.capacity, np.int64)
-            if cfg.capacity is not None else None
-            for tp in self.fleet.topos[1:]]
+        # residual aggregation capacity (None = unbounded): one ledger over
+        # the fleet's physical switches, claimed through whichever tree a
+        # job was given; self._residual is tree 0's view of it
+        self._set_ledgers()
         # shared-core rates join every fingerprint: a placement solved
         # against one core pricing must not serve a different one
         self._core_key = self.fleet.core_rho.tobytes()
@@ -194,6 +191,13 @@ class Orchestrator:
 
     # -- properties ----------------------------------------------------------
     @property
+    def fabric_ledger(self) -> np.ndarray | None:
+        """Free aggregator slots per physical switch of the fleet (None
+        without a capacity). Tree g's node v is switch
+        ``fleet.switch_id[g][v]``."""
+        return self._fabric
+
+    @property
     def n_alive(self) -> int:
         return int((self.alive & ~self.quarantined).sum())
 
@@ -203,6 +207,34 @@ class Orchestrator:
         return self.topo0.n_devices / max(1, self.n_alive)
 
     # -- internal ------------------------------------------------------------
+    def _set_ledgers(self) -> None:
+        """A full physical ledger, tree 0's view of it (``_residual``;
+        the fleet numbers tree 0's switches first), and ``_residuals``,
+        one view per tree where every tree owns a contiguous run of
+        switches (None where trees share switches)."""
+        cap = self.cfg.capacity
+        fl = self.fleet
+        self._fabric = (None if cap is None
+                        else np.full(fl.n_switches, cap, np.int64))
+        n0 = fl.topos[0].tree.n
+        self._residual = None if cap is None else self._fabric[:n0]
+        if cap is None:
+            self._residuals = [None] * fl.n_trees
+        elif not fl.shared:         # ids in order of first appearance
+            self._residuals = [self._residual] + [
+                self._fabric[ids[0]: ids[0] + ids.size]
+                for ids in fl.switch_id[1:]]
+        else:
+            self._residuals = None
+
+    def _claim(self, trees, blues, delta: int) -> None:
+        """Add ``delta`` to the physical ledger at every blue switch of
+        each (tree, mask) pair."""
+        ids = [self.fleet.switch_id[int(g)][np.asarray(b, bool)]
+               for g, b in zip(trees, blues)]
+        if ids:
+            np.add.at(self._fabric, np.concatenate(ids), delta)
+
     def _avail(self) -> np.ndarray | None:
         if self._residual is None:
             return None
@@ -487,12 +519,16 @@ class Orchestrator:
                     # the evicted foreign claims come off the youngest
                     # registered jobs holding s
                     if shortfall:
+                        held = {j.job_id: np.nonzero(
+                            self.fleet.switch_id[j.tree] == s)[0]
+                            for j in self.jobs.values()}
                         holders = sorted(
                             (j for j in self.jobs.values()
-                             if j.tree == 0 and j.blue[s]),
+                             if held[j.job_id].size
+                             and j.blue[held[j.job_id][0]]),
                             key=lambda j: -j.order)
                         for j in holders[:shortfall]:
-                            j.blue[s] = False
+                            j.blue[held[j.job_id][0]] = False
                 self._residual[s] = eff_new - claims
         else:
             # unbounded capacity: only a dead plane (scale 0) forces the
@@ -624,9 +660,7 @@ class Orchestrator:
         self.switch_blocked = np.zeros(n, bool)
         self._link_rate = np.ones(n)
         self._switch_scale = np.ones(n)
-        self._residual = (np.full(n, self.cfg.capacity, np.int64)
-                          if self.cfg.capacity is not None else None)
-        self._residuals = [self._residual]
+        self._set_ledgers()
         self.jobs.clear()             # rescale drains every foreign claim
         self._allred_util.clear()
         self._admission_cache.clear()
@@ -644,12 +678,12 @@ class Orchestrator:
     def _register_job(self, blue: np.ndarray, prog: ReduceProgram,
                       tree: int = 0, priority: int = 0) -> JobRecord:
         """File an admitted workload's claims in the job registry."""
-        base = self._allred_util.get(tree)
-        if base is None:
-            tp = self.fleet.topos[tree]
+        tp = self.fleet.topos[tree]
+        base = self._allred_util.get(id(tp))
+        if base is None:        # candidate trees share their topology
             base = build_program(
                 tp, np.zeros(tp.tree.n, bool)).utilization
-            self._allred_util[tree] = base
+            self._allred_util[id(tp)] = base
         self._job_seq += 1
         rec = JobRecord(
             job_id=self._job_seq, tree=int(tree),
@@ -662,33 +696,45 @@ class Orchestrator:
 
     @telemetry.traced("orchestrator.release")
     def release_workloads(self, job_ids) -> int:
-        """Release admitted jobs' capacity claims; returns claims freed."""
-        freed = 0
-        for jid in job_ids:
-            j = self.jobs.pop(int(jid), None)
-            if j is None:
-                raise KeyError(f"unknown job id {jid}")
-            self._residuals[j.tree][j.blue] += 1
-            freed += int(j.blue.sum())
-        return freed
+        """Release admitted jobs' capacity claims on the physical ledger;
+        returns claims freed."""
+        jobs = []
+        try:
+            for jid in job_ids:
+                j = self.jobs.pop(int(jid), None)
+                if j is None:
+                    raise KeyError(f"unknown job id {jid}")
+                jobs.append(j)
+        finally:                    # the jobs before a bad id are released
+            with telemetry.span("orchestrator.fabric_ledger"):
+                self._claim([j.tree for j in jobs], [j.blue for j in jobs],
+                            1)
+        return sum(int(j.blue.sum()) for j in jobs)
 
     def _preempt(self, policy: PreemptionPolicy, res) -> tuple[list, int]:
         """Stage 1 of preemptive admission: evict registered jobs holding
-        claims on the switches the failed wave exhausted (instant — no
-        solve; the caller re-solves once against the freed ledger).
-        Returns ``(victim job ids, claims freed on exhausted switches)``.
+        claims on the physical switches the failed wave exhausted
+        (instant — no solve; the caller re-solves once against the freed
+        ledger). Returns ``(victim job ids, claims freed on exhausted
+        switches)``.
         """
-        scarce = [np.asarray(ra) == 0 for ra in res.residual_after]
+        ra = res.residual_after
+        scarce = np.zeros(self._fabric.size, bool)
+        if isinstance(ra, np.ndarray):              # per physical switch
+            scarce[:] = ra == 0
+        else:                                       # the single tree 0
+            scarce[: ra[0].size] = np.asarray(ra[0]) == 0
         shortfall = int(np.asarray(res.admission_dropped).sum())
-        cands = [j for j in self.jobs.values()
-                 if j.tree < len(scarce) and np.any(j.blue & scarce[j.tree])]
+        short = {j.job_id: j.blue & scarce[self.fleet.switch_id[j.tree]]
+                 for j in self.jobs.values()}
+        cands = [j for j in self.jobs.values() if short[j.job_id].any()]
         victims: list[int] = []
         freed = 0
         for j in policy.order_victims(cands):
             if freed >= shortfall or len(victims) >= policy.max_victims:
                 break
-            self._residuals[j.tree][j.blue] += 1
-            freed += int((j.blue & scarce[j.tree]).sum())
+            self._claim([j.tree], [j.blue], 1)
+            freed += int(short[j.job_id].sum())
             victims.append(j.job_id)
             del self.jobs[j.job_id]
         return victims, freed
@@ -745,16 +791,26 @@ class Orchestrator:
         tenants away *before* the claim accounting collides — fewer
         serial collision fallbacks, same bounded-capacity guarantee.
 
-        ``fleet=[c_0, .., c_{N-1}]`` (instead of ``count``) admits
-        ``c_g`` workloads onto tree ``g`` of the orchestrator's
-        :class:`~repro.collectives.topology.Fleet` with one *coupled*
+        ``fleet=[c_0, .., c_{G-1}]`` (instead of ``count``) admits
+        ``c_i`` workloads onto candidate group ``i`` of the orchestrator's
+        :class:`~repro.collectives.topology.Fleet` (by default group i is
+        tree i alone) with one *coupled*
         :func:`~repro.collectives.schedule.plan_fleet` solve — tenants on
-        different trees trade placements through the fleet's shared core
-        links — and per-tree capacity claims: each tenant claims against
-        its own tree's residual ledger, collision fallbacks re-solve on
-        the tenant's own tree only. Requires ``congestion_aware=True``
-        (fleet admission *is* the congestion driver); a plain-topology
-        orchestrator accepts ``fleet=[c]`` as the degenerate N=1 case.
+        different trees trade placements through the fleet's shared
+        links — and claims on one ledger of the fleet's *physical*
+        switches. A tenant of a group may aggregate on any of its group's
+        trees (a pod's paths through a multi-rooted fabric): admission
+        order is the tenant order; a claim on a switch is admitted while
+        that physical switch has a free slot, whichever tree it comes
+        through; each admitted tenant's utilization is the least that any
+        tree of its group allows with the slots the tenants admitted
+        before it left; among trees of equal utilization the loop chooses
+        by link congestion. Each tenant's program is built on the tree it
+        was given, which its job records (``JobRecord.tree``); collision
+        fallbacks re-solve on that tree only. Requires
+        ``congestion_aware=True`` (fleet admission *is* the congestion
+        driver); a plain-topology orchestrator accepts ``fleet=[c]`` as
+        the degenerate N=1 case.
 
         ``device_admission=True`` (congestion-aware only) moves the hard
         admission *inside* the device-resident penalty loop: the solver
@@ -935,37 +991,41 @@ class Orchestrator:
                                device_admission: bool = False,
                                preemption: PreemptionPolicy | None = None,
                                priority: int = 0) -> list[ReduceProgram]:
-        """Fleet admission: one coupled solve, per-tree capacity claims."""
-        N = self.fleet.n_trees
-        if len(counts) != N or any(c < 1 for c in counts):
+        """Fleet admission: one coupled solve, claims on the physical
+        ledger. ``counts`` gives tenants per candidate group."""
+        G = self.fleet.n_groups
+        if len(counts) != G or any(c < 1 for c in counts):
             raise ValueError(f"fleet counts must give >=1 workloads for "
-                             f"each of the {N} trees, got {counts}")
+                             f"each of the {G} trees, got {counts}")
         if capacity_priced:
             if "capacity" in driver_kw:
                 raise ValueError("capacity_priced=True supplies the "
                                  "orchestrator's residual-capacity snapshot; "
                                  "don't also pass capacity= explicitly")
-            driver_kw = dict(driver_kw, capacity=[
-                r.astype(np.float64) for r in self._residuals])
-        tree_of = [g for g, c in enumerate(counts) for _ in range(c)]
+            driver_kw = dict(driver_kw,
+                             capacity=self._fabric.astype(np.float64))
         if device_admission:
-            return self._begin_fleet_device(counts, tree_of, preemption,
-                                            priority, driver_kw)
-        snaps = [r > 0 for r in self._residuals]
+            return self._begin_fleet_device(counts, preemption, priority,
+                                            driver_kw)
+        sw = self.fleet.switch_id
+        avails = None
+        if not self.fleet.shared:
+            avails = [self._fabric[sw[self.fleet.groups[i][0]]] > 0
+                      for i, c in enumerate(counts) for _ in range(c)]
         planned, driver_res = plan_fleet(
-            self.fleet, self.cfg.k, counts=counts,
-            avails=[snaps[g] for g in tree_of], **driver_kw)
+            self.fleet, self.cfg.k, counts=counts, avails=avails,
+            **driver_kw)
+        tree_of = [int(g) for g in driver_res.tree_of]
         progs: list[ReduceProgram] = []
         admitted: list[np.ndarray] = []
         collisions = 0
         for g, (blue, prog) in zip(tree_of, planned, strict=True):
-            res_g = self._residuals[g]
-            if np.any(blue & (res_g <= 0)):        # capacity collision
+            free = self._fabric[sw[g]] > 0
+            if np.any(blue & ~free):               # capacity collision
                 blue, prog = plan(self.fleet.topos[g], self.cfg.k,
-                                  avail=res_g > 0,
-                                  strategy=self.cfg.strategy)
+                                  avail=free, strategy=self.cfg.strategy)
                 collisions += 1
-            res_g[blue] -= 1                       # this tree's ledger
+            self._claim([g], [blue], -1)
             self.utilization_history.append(prog.utilization)
             self._register_job(blue, prog, tree=g, priority=priority)
             progs.append(prog)
@@ -986,7 +1046,8 @@ class Orchestrator:
                 trees, tree_of, loads, admitted,
                 core_rho=self.fleet.core_rho if has_core else None,
                 core_path=self.fleet.core_path if has_core else None,
-                rho_weighted=driver_kw.get("rho_weighted", False))
+                rho_weighted=driver_kw.get("rho_weighted", False),
+                link_id=self.fleet.link_id if self.fleet.shared else None)
             n_big = max(t.n for t in trees)
             stack = np.zeros((len(admitted), n_big), bool)
             for t, b in enumerate(admitted):
@@ -999,21 +1060,20 @@ class Orchestrator:
         self.last_congestion = driver_res
         return progs
 
-    def _begin_fleet_device(self, counts: list[int], tree_of: list[int],
+    def _begin_fleet_device(self, counts: list[int],
                             preemption: PreemptionPolicy | None,
                             priority: int,
                             driver_kw: dict) -> list[ReduceProgram]:
-        """Fleet admission with per-tree ledgers inside the loop — the
+        """Fleet admission with the physical ledger inside the loop — the
         multi-tree twin of :meth:`_begin_device_admission` (no collision
-        fallbacks; at most one preemption pass)."""
+        fallbacks; at most one preemption pass). Each job records the
+        tree it was given."""
         solves = 0
         victims: list[int] = []
         while True:
-            snaps = [r > 0 for r in self._residuals]
             planned, res = plan_fleet(
                 self.fleet, self.cfg.k, counts=counts,
-                avails=[snaps[g] for g in tree_of],
-                residual=[r.copy() for r in self._residuals], **driver_kw)
+                residual=self._fabric.copy(), **driver_kw)
             solves += 1
             dropped = int(np.asarray(res.admission_dropped).sum())
             if dropped == 0 or preemption is None or solves > 1:
@@ -1026,16 +1086,18 @@ class Orchestrator:
                 "policy": preemption.kind, "victims": tuple(evicted),
                 "freed": int(freed), "dropped_before": dropped})
         with telemetry.span("orchestrator.register"):
+            with telemetry.span("orchestrator.fabric_ledger"):
+                self._claim(res.tree_of, [p.blue for p in planned], -1)
+                if np.any(self._fabric < 0):
+                    raise RuntimeError("in-loop fleet admission returned an "
+                                       "infeasible placement — "
+                                       "engine/ledger disagreement")
             progs: list[ReduceProgram] = []
-            for g, (blue, prog) in zip(tree_of, planned, strict=True):
-                self._residuals[g][blue] -= 1
+            for g, (blue, prog) in zip(res.tree_of, planned, strict=True):
                 self.utilization_history.append(prog.utilization)
-                self._register_job(blue, prog, tree=g, priority=priority)
+                self._register_job(blue, prog, tree=int(g),
+                                   priority=priority)
                 progs.append(prog)
-            if any(np.any(r < 0) for r in self._residuals):
-                raise RuntimeError("in-loop fleet admission returned an "
-                                   "infeasible placement — engine/ledger "
-                                   "disagreement")
         self.last_congestion = res
         self.last_admission = {
             "path": "device", "solves": solves, "round_trips": solves,

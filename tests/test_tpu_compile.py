@@ -126,3 +126,28 @@ def test_device_driver_compiles_for_v5e(one_chip, on_tpu, monkeypatch):
     compiled = congestion._device_driver.lower(
         *_shapes(args, one_chip), **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_path_loop_compiles_for_v5e(one_chip, on_tpu, monkeypatch):
+    """The path-choice loop at its cell's size: a 64-tenant wave on the
+    k=16 fat-tree, every tenant's 64 candidate trees (4,096 rows) in the
+    fold, with the physical ledger, captured from ``plan_fleet``, then
+    compiled."""
+    from repro.collectives import fat_tree_fleet, plan_fleet
+
+    def capture(*args, **kw):
+        raise _Captured(args, kw)
+
+    monkeypatch.setattr(congestion, "_device_paths", capture)
+    fleet = fat_tree_fleet(16)
+    counts = 1 + np.random.default_rng(0).multinomial(48, np.full(16, 1 / 16))
+    with pytest.raises(_Captured) as got:
+        plan_fleet(fleet, 4, counts=counts.tolist(),
+                   residual=np.full(fleet.n_switches, 2, np.int64))
+    args, kw = got.value.args
+    assert kw["use_pallas"] and not kw["interpret"] and kw["admit"]
+    assert args[0].shape[0] == 64 * 64
+    monkeypatch.undo()
+    compiled = congestion._device_paths.lower(
+        *_shapes(args, one_chip), **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
