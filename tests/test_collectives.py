@@ -68,6 +68,66 @@ def test_tree_allreduce_equals_psum_subprocess():
 
 
 # ---------------------------------------------------------------------------
+# executor against a numpy replay of the program (one subprocess, 8 fake
+# devices: tests/helpers/executor_replay_check.py)
+# ---------------------------------------------------------------------------
+
+_SWEEP = [f"k{k}-{s}" for k in (0, 1, 2, 4, 15)
+          for s in ("soar", "top", "max", "random")]
+REPLAY_CASES = (_SWEEP + ["all_red", "all_blue", "failed_devices"]
+                + [f"spill{i}" for i in range(8)]
+                + ["degraded_root", "spill_rack", "spill_two_racks",
+                   "spill_pod_and_rack"])
+
+
+@pytest.fixture(scope="module")
+def replay_run():
+    """The helper's output: {"CASE": {name: line}, "FEATURE": {name: n},
+    "SCATTERS": n}."""
+    import os
+    root = pathlib.Path(__file__).parent.parent
+    out = subprocess.run(
+        [sys.executable, str(root / "tests" / "helpers"
+                             / "executor_replay_check.py")],
+        cwd=str(root), env={**os.environ, "PYTHONPATH": "src"},
+        capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    got = {"CASE": {}, "FEATURE": {}, "SCATTERS": None}
+    for line in out.stdout.splitlines():
+        head, _, rest = line.partition(" ")
+        if head == "CASE":
+            name, _, verdict = rest.partition(" ")
+            got["CASE"][name] = verdict
+        elif head == "FEATURE":
+            name, n = rest.split()
+            got["FEATURE"][name] = int(n)
+        elif head == "SCATTERS":
+            got["SCATTERS"] = int(rest)
+    return got
+
+
+@pytest.mark.parametrize("case", REPLAY_CASES)
+def test_executor_matches_numpy_replay_bitwise(replay_run, case):
+    """tree_allreduce (flat) and reduce_local (shaped values) equal a plain
+    float32 replay of the program byte for byte, and every delivery lies
+    inside the slot buffer (off + cnt <= n_slots)."""
+    assert set(replay_run["CASE"]) == set(REPLAY_CASES)
+    assert replay_run["CASE"][case] == "ok", replay_run["CASE"][case]
+
+
+@pytest.mark.parametrize("feature", ["slab_gt_1", "FoldOp", "CompactOp",
+                                     "root_count_gt_1"])
+def test_replay_cases_cover_degraded_ops(replay_run, feature):
+    assert replay_run["FEATURE"][feature] > 0
+
+
+def test_cell_reduce_lowers_without_scatter(replay_run):
+    """The gradient-sync cell's program (dp_fleet(4), k = 2) lowered inside
+    shard_map on 4 devices: no slot update is a scatter."""
+    assert replay_run["SCATTERS"] == 0
+
+
+# ---------------------------------------------------------------------------
 # fail_devices state-corruption regressions
 # ---------------------------------------------------------------------------
 

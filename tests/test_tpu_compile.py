@@ -10,12 +10,14 @@ without a chip).
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
 from repro.collectives import build_fleet
 from repro.core import bt, sample_load
@@ -24,7 +26,7 @@ from repro.engine import EngineOptions, batched, congestion, pallas_fold
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -39,10 +41,15 @@ def one_chip():
         jax.config.update("jax_enable_compilation_cache", False)
         cc.reset_cache()
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", was)
             cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e):
+    return SingleDeviceSharding(v5e.devices[0])
 
 
 @pytest.fixture
@@ -151,3 +158,36 @@ def test_path_loop_compiles_for_v5e(one_chip, on_tpu, monkeypatch):
     compiled = congestion._device_paths.lower(
         *_shapes(args, one_chip), **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+_F32 = re.compile(r"f32\[([\d,]*)\]\{([^}]*)\}")
+
+
+@pytest.mark.parametrize("shape", [(4, 8, 1408, 2048), (4, 2048, 576)],
+                         ids=["expert_w_down", "attn_w_dkv"])
+def test_grad_sync_keeps_leaf_layout_for_v5e(v5e, shape):
+    """The gradient-sync cell's reduce (``dp_fleet(4)``, k = 2) of one
+    DeepSeek-V2-Lite leaf on a v5e:2x2: every gradient-sized array, the
+    slot buffer included, keeps the leaf's ``(8,128)`` tiling, and nothing
+    relays the leaf out (a slot as a ``(1, D)`` or ``(2, D)`` row is tiled
+    ``(1,128)`` or ``(2,128)``, with unaligned slot writes)."""
+    from repro.collectives.tree_allreduce import reduce_local
+    from repro.launch.train import dp_fleet
+    from repro.runtime import Orchestrator, OrchestratorConfig
+
+    prog = Orchestrator(dp_fleet(4), OrchestratorConfig(k=2)).program
+    mesh = Mesh(np.asarray(v5e.devices), ("data",))
+    sync = jax.jit(jax.shard_map(
+        lambda g: reduce_local(g[0], prog, "data"), mesh=mesh,
+        in_specs=PartitionSpec("data"), out_specs=PartitionSpec()))
+    arg = jax.ShapeDtypeStruct((4, *shape), jnp.float32,
+                               sharding=NamedSharding(mesh,
+                                                      PartitionSpec("data")))
+    hlo = sync.lower(arg).compile().as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    n = int(np.prod(shape))
+    big = [(dims, layout) for dims, layout in _F32.findall(entry)
+           if dims and int(np.prod([int(d) for d in dims.split(",")])) >= n]
+    assert big
+    assert all("T(8,128)" in layout for _, layout in big), big
+    assert not re.search(r"= f32\S* copy\(", entry)
